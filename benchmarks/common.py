@@ -97,18 +97,20 @@ def standalone_bench(key: str, fn: Callable) -> None:
 
 def run_device_subprocess(code: str, devices: int = 8,
                           timeout: int = 1800) -> dict:
-    """Run benchmark ``code`` in a child python with N host devices and
-    parse its ``print("JSON" + json.dumps(payload))`` sentinel line.
+    """Run benchmark ``code`` in a child python on N virtual CPU devices
+    and parse its ``print("JSON" + json.dumps(payload))`` sentinel line.
 
     jax locks the host device count at first init, so anything needing a
     mesh runs in a subprocess with XLA_FLAGS set before jax imports —
     the shared boilerplate of multi_session / net_load style benches.
+    These are host-device rehearsals: the child is pinned to the CPU
+    backend, so it never competes with a parent for the chip.
     """
     import subprocess
     import sys
     import textwrap
 
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")

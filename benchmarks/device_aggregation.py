@@ -1,26 +1,22 @@
 """Device data-plane benchmark: SAFE chain vs psum vs BON on a host mesh.
 
-Runs in a subprocess with 8 host devices (the bench process itself stays
-single-device). Wall time on CPU is not TPU-predictive — the *derived*
-columns (bytes over the learner axis per aggregation, PRF work) are the
-roofline-relevant outputs; wall time just sanity-checks the orderings.
+Runs in a subprocess on 8 virtual CPU devices (the bench process itself
+stays single-device, and the child never takes a chip). Wall time on CPU
+is not TPU-predictive — the *derived* columns (bytes over the learner
+axis per aggregation, PRF work) are the roofline-relevant outputs; wall
+time just sanity-checks the orderings.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
-from benchmarks.common import emit, save_json
+from benchmarks.common import emit, run_device_subprocess, save_json
 
 _CODE = """
 import json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import make_aggregator
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 n, V = 8, 1 << 20
 vals = jnp.asarray(np.random.RandomState(0).uniform(-1, 1, (n, V))
                    .astype(np.float32))
@@ -55,16 +51,7 @@ print("JSON" + json.dumps(out))
 
 
 def run() -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(repo, "src")
-    proc = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
-                          capture_output=True, text=True, timeout=1200,
-                          env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(proc.stderr[-2000:])
-    payload = json.loads(proc.stdout.split("JSON", 1)[1])
+    payload = run_device_subprocess(_CODE, devices=8, timeout=1200)
     for name, row in payload.items():
         emit(f"device_agg/{name}", row["wall_s"] * 1e6,
              f"axis_MB={row['axis_bytes_per_learner']/2**20:.0f} "

@@ -58,12 +58,6 @@ def run() -> dict:
          f"fused 8B/elem vs 16B/elem unfused (2.0x HBM)")
     emit("kernel/chain_combine", t_chain * 1e6,
          f"fused 12B/elem vs 32B/elem unfused (2.7x HBM)")
-    # projected TPU v5e time for one fused hop over a 100M-param vector
-    v5e_bw = 819e9
-    t_hop = 100e6 * 12 / v5e_bw
-    emit("kernel/projected_v5e_hop_100M", t_hop * 1e6,
-         "memory-bound @819GB/s")
-    payload["projected_v5e_hop_100M_s"] = t_hop
     save_json("kernel_bench", payload)
     return payload
 

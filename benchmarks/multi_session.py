@@ -18,8 +18,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import ChainConfig, SecureAggregator
 from repro.serve import AggregationEngine
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 n, V = 8, 4096
 rng = np.random.RandomState(0)
 cfg = ChainConfig(num_learners=n, mode="safe")

@@ -51,7 +51,8 @@ from repro.net.loadgen import run_engine_load, run_protocol_load
 out = {}
 
 async def engine_plane():
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     n, V = 8, 1024
     for S in (4, 16):
         cfg = ChainConfig(num_learners=n, mode="safe")

@@ -14,16 +14,14 @@ import jax.numpy as jnp
 from repro.configs import get_smoke_config
 from repro.core import make_aggregator
 from repro.data import make_federated_batches
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.train import MetricsLogger, make_train_step
 
 
 def main():
-    # 4 learners (cross-org chain) × tensor parallelism. TP > 1 needs
-    # partial-manual shard_map (jax >= 0.6); older stacks fall back to
-    # TP = 1 — see ARCHITECTURE.md "Version compatibility".
-    tp = 2 if jax.__version_info__ >= (0, 6, 0) else 1
-    mesh = jax.make_mesh((4, tp), ("data", "model"))
+    # 4 learners (cross-org chain) × 2-way tensor parallelism
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("internlm2-1.8b")
     model = Model(cfg)
 

@@ -16,6 +16,4 @@ Public API surface:
 See ARCHITECTURE.md for the two-plane + topology-layer picture.
 """
 
-from repro import compat  # noqa: F401  (installs jax API shims on old jax)
-
 __version__ = "1.0.0"

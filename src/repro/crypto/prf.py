@@ -94,15 +94,18 @@ def keystream_pair_lanes(key: jax.Array, n: int, counter_base: jax.Array | int =
 
     This is the schedule the Pallas kernel implements: block ``b`` yields
     words ``(2b, 2b+1)``. Bit-exact oracle for ``kernels.threefry_mask_add``.
+
+    Word ``i`` evaluates its own block and selects lane ``i & 1``, as the
+    kernel does. Interleaving the two lanes instead would need a
+    ``[n/2, 2]`` intermediate, which the TPU tiles to 128 lanes (64x
+    its size); this form keeps every array 1-D.
     """
     if isinstance(counter_base, (int, np.integer)):
         counter_base = np.uint32(int(counter_base) & 0xFFFFFFFF)
     base = jnp.asarray(counter_base, jnp.uint32)
-    nblk = (n + 1) // 2
-    idx = jnp.arange(nblk, dtype=jnp.uint32)
-    y0, y1 = threefry2x32(key, base + idx, jnp.zeros_like(idx))
-    out = jnp.stack([y0, y1], axis=-1).reshape(-1)
-    return out[:n]
+    idx = jnp.arange(n, dtype=jnp.uint32)
+    y0, y1 = threefry2x32(key, base + (idx >> 1), jnp.zeros_like(idx))
+    return jnp.where((idx & 1).astype(jnp.bool_), y1, y0)
 
 
 def derive_key(master: jax.Array, *tags: int) -> jax.Array:

@@ -5,8 +5,9 @@ chain_combine     — fused SAFE non-initiator hop (decrypt+add+re-encrypt)
 bon_mask          — fused BON pairwise masking (baseline hot spot)
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and a jit'd wrapper in
-``ops.py`` (interpret=True automatically off-TPU).
+``ops.py`` (compiled on a TPU, interpreted on the CPU backend).
 """
-from repro.kernels.ops import mask_add, chain_combine, bon_mask
+from repro.kernels.ops import (bon_mask, chain_combine, chain_combine_batched,
+                               mask_add)
 
-__all__ = ["mask_add", "chain_combine", "bon_mask"]
+__all__ = ["mask_add", "chain_combine", "chain_combine_batched", "bon_mask"]

@@ -1,9 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True on CPU hosts (this container) and False on
-real TPU backends — callers never need to pass it. The chain data plane
-(`core/chain.py`) can route its mask arithmetic through these via
-``use_kernels=True`` in the high-level ops below.
+``interpret=None`` (the default) compiles the kernel for the TPU on the
+``"tpu"`` backend and runs the Pallas interpreter on the ``"cpu"``
+backend (tests, rehearsals); any other backend raises rather than
+silently interpreting. Pass ``interpret=`` explicitly to pin the choice.
+The chain data plane (``core/chain.py``) does not call these yet: it
+builds its pads with the jnp keystream (``crypto/prf.py``).
 """
 from __future__ import annotations
 
@@ -20,7 +22,12 @@ from repro.kernels.bon_mask import bon_mask as _bon_mask
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for a TPU or interpret on the CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def _u32(counter_base):

@@ -1,4 +1,8 @@
-"""Production meshes (TPU v5e).
+"""Mesh construction (TPU v5e).
+
+``make_mesh`` is the one mesh constructor of the repo: every axis is
+``AxisType.Auto``. ``jax.make_mesh`` alone makes Explicit axes, on which
+``with_sharding_constraint`` refuses the train step's Megatron specs.
 
 Single pod: 16×16 = 256 chips, axes (data, model) — 'data' is the
 learner/chain axis (one SAFE learner per data rank), 'model' the
@@ -13,34 +17,29 @@ Defined as functions so importing this module never touches device state
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
 import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Sequence | None = None) -> Mesh:
+    """Mesh of ``shape`` over the first ``prod(shape)`` devices (or the
+    given ``devices``, e.g. a described TPU topology), all axes Auto."""
+    shape, axes = tuple(shape), tuple(axes)
     n = int(np.prod(shape))
-    devices = jax.devices()
-    if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+    if devices is None:
+        devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            "run under XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{max(n, 512)} (dryrun.py sets this automatically)")
-    # more devices than needed (e.g. 512 placeholders, single-pod mesh):
-    # use the first n
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+            f"need {n} devices for mesh {shape}, have {len(devices)}")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=list(devices)[:n])
 
 
-def make_test_mesh(data: int = 4, model: int = 2, pod: int = 1):
-    """Small host-device mesh for tests/examples."""
-    if pod > 1:
-        from jax.sharding import Mesh
-        devs = np.asarray(jax.devices()[: pod * data * model])
-        return Mesh(devs.reshape(pod, data, model), ("pod", "data", "model"))
-    from jax.sharding import Mesh
-    devs = np.asarray(jax.devices()[: data * model])
-    return Mesh(devs.reshape(data, model), ("data", "model"))
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return make_mesh(shape, axes)

@@ -1,10 +1,14 @@
 """Training launcher.
 
 Examples:
-  # small real run on host devices (the quickstart path)
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+  # small real run on 8 virtual CPU devices (the quickstart path)
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
   python -m repro.launch.train --arch internlm2-1.8b --smoke \\
       --steps 50 --learners 4 --model-shards 2 --aggregator safe
+
+  # on a TPU v5e 2x2 host: one learner per chip
+  python -m repro.launch.train --arch internlm2-1.8b --smoke \\
+      --learners 4 --model-shards 1
 
   # federated (FedAvg, weighted SAFE delta aggregation)
   ... --federated --local-steps 4
@@ -12,8 +16,6 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import os
 import time
 
 
@@ -43,27 +45,23 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    needed = args.learners * args.model_shards
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={needed}")
-
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config, get_smoke_config
     from repro.core import make_aggregator
     from repro.data import make_federated_batches
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.train import (MetricsLogger, make_federated_round,
                              make_train_step)
     from repro.ckpt import save_checkpoint, restore_checkpoint, latest_step
-    from jax.sharding import Mesh
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
-    devs = np.asarray(jax.devices()[:needed]).reshape(
-        args.learners, args.model_shards)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh((args.learners, args.model_shards), ("data", "model"))
 
     agg = make_aggregator(args.aggregator, args.learners, axis="data",
                           pipelined=args.pipelined, subgroups=args.subgroups,

@@ -32,7 +32,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.chain import chain_aggregate_batched
 from repro.core.session import AggSession
@@ -74,6 +74,7 @@ class AggregationEngine:
         #: (used by net/broker.py to resolve wire-side wait_session
         #: long-polls without scanning slots).
         self.on_complete: Optional[Callable[[AggSession], None]] = None
+        self._vals_sharding = NamedSharding(mesh, P(None, cfg.axis))
         self._program = self._build_program()
 
     # ---- compiled program ------------------------------------------------
@@ -159,8 +160,11 @@ class AggregationEngine:
             alive[i] = sess.alive
             wts[i] = sess.weights
 
+        # place each rank's [S, 1, V] slice on its own device: staging the
+        # whole [S, n, V] on one device first takes n× its share of HBM
+        vals = jax.device_put(vals, self._vals_sharding)
         with jax.set_mesh(self.mesh):
-            out = self._program(jnp.asarray(vals), jnp.asarray(prov_w),
+            out = self._program(vals, jnp.asarray(prov_w),
                                 jnp.asarray(master_w), jnp.asarray(ctrs),
                                 jnp.asarray(alive), jnp.asarray(wts),
                                 jnp.asarray(rots))

@@ -14,17 +14,6 @@ import textwrap
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def partial_manual_supported() -> bool:
-    """True when shard_map with *partial* manual axes (manual 'data' +
-    auto 'model') works. jax < 0.6's XLA SPMD partitioner cannot lower
-    axis_index/collectives inside a partial-manual region ("PartitionId
-    instruction is not supported..." / manual-subgroup check failures) —
-    tensor-parallel train tests are skipped there. Fully-manual regions
-    (the whole aggregation core) work on every supported jax."""
-    import jax
-    return jax.__version_info__ >= (0, 6, 0)
-
-
 def run_multidevice(code: str, devices: int = 8, timeout: int = 900) -> str:
     """Run ``code`` in a child python with N host devices; returns stdout.
     Raises on nonzero exit (stderr tail included)."""

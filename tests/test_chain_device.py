@@ -9,8 +9,9 @@ from helpers import run_multidevice
 CHAIN_CODE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import make_aggregator
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 n, V = 8, 37
 rng = np.random.RandomState(0)
 vals = jnp.asarray(rng.uniform(-2, 2, size=(n, V)).astype(np.float32))
@@ -89,10 +90,9 @@ print("ALL_CHAIN_OK")
 
 HIERARCHICAL_CODE = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh
 from repro.core import make_aggregator
-devs = np.asarray(jax.devices()[:8]).reshape(2, 4)
-mesh = Mesh(devs, ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("pod", "data"))
 n, V = 4, 19
 rng = np.random.RandomState(1)
 # one value matrix per pod; hierarchical = mean over pods of pod means
@@ -121,7 +121,8 @@ from jax.sharding import PartitionSpec as P
 
 # Capture what actually crosses the wire: run the chain but return every
 # rank's outgoing value; check none equals an unmasked partial sum.
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 n, V = 4, 16
 cfg = ChainConfig(num_learners=n, mode="safe")
 rng = np.random.RandomState(0)

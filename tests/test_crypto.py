@@ -45,6 +45,16 @@ class TestThreefry:
             np.asarray(keystream_pair_lanes(jnp.asarray(key), n, base)),
             keystream_pair_lanes_np(key, n, base))
 
+    @pytest.mark.parametrize("n", [0, 1, 2, 129, 8193])
+    @pytest.mark.parametrize("base", [0, 2**31 + 1, 2**32 - 3, 2**33 + 7])
+    def test_keystream_pair_lanes_interleaves_both_lanes(self, n, base):
+        """Word 2b / 2b+1 = lane 0 / lane 1 of block base+b, including
+        bases past 2**32 (wrapped) and counters that wrap mid-stream."""
+        key = np.array([0x12345678, 0x9ABCDEF0], np.uint32)
+        np.testing.assert_array_equal(
+            np.asarray(keystream_pair_lanes(jnp.asarray(key), n, base)),
+            keystream_pair_lanes_np(key, n, base & 0xFFFFFFFF))
+
     def test_keystream_disjoint_counters_differ(self):
         key = jnp.array([1, 2], jnp.uint32)
         a = np.asarray(keystream(key, 128, 0))

@@ -1401,8 +1401,9 @@ import asyncio, numpy as np, jax
 from repro.core.types import ChainConfig
 from repro.serve import AggregationEngine
 from repro.net import SafeBroker, WireClient
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 n, V, S = 8, 32, 4
 cfg = ChainConfig(num_learners=n, mode="safe")
 engine = AggregationEngine(mesh, cfg, slots=S, payload_words=V)

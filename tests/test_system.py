@@ -9,13 +9,10 @@ import numpy as np
 
 import pytest
 
-from helpers import partial_manual_supported, run_multidevice
+from helpers import run_multidevice
 from repro.core.protocol import run_safe_round
 
 
-@pytest.mark.skipif(not partial_manual_supported(), reason=
-    "partial-manual shard_map (manual data + auto model) unsupported "
-    "by this jax/XLA SPMD partitioner — see ARCHITECTURE.md")
 def test_end_to_end_system():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
@@ -27,7 +24,8 @@ from repro.train.train_step import make_train_step
 from repro.serve.engine import ServeEngine, Request
 
 # ---- train with SAFE over 4 learners × 2-way TP -------------------------
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("internlm2-1.8b")
 model = Model(cfg)
 agg = make_aggregator("safe", 4, axis="data")
@@ -64,7 +62,8 @@ def test_control_plane_matches_data_plane_average():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import make_aggregator
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 vals = jnp.asarray(np.random.RandomState(5).uniform(-1, 1, (4, 33))
                    .astype(np.float32))
 agg = make_aggregator("safe", 4)

@@ -101,7 +101,8 @@ class TestCrossPlaneAgreement:
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import make_aggregator
 from repro.core.protocol import run_safe_round
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 rng = np.random.RandomState(0)
 n, V = 8, 33
 for subgroups, failed in [(1, []), (1, [4, 6]), (1, [1]),
@@ -157,7 +158,8 @@ class TestFailoverEdgeCases:
         out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import make_aggregator
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 n, V = 8, 21
 vals = np.random.RandomState(3).uniform(-1, 1, (n, V)).astype(np.float32)
 alive = jnp.array([1, 1, 1, 1, 0, 0, 1, 0], jnp.float32)

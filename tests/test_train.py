@@ -8,12 +8,9 @@ learner, deltas chunk-streamed through the asyncio broker) publishes a
 model delta bit-identical to the in-SPMD ``train/federated.py`` round."""
 import pytest
 
-from helpers import partial_manual_supported, run_multidevice
+from helpers import run_multidevice
 
 
-@pytest.mark.skipif(not partial_manual_supported(), reason=
-    "partial-manual shard_map (manual data + auto model) unsupported "
-    "by this jax/XLA SPMD partitioner — see ARCHITECTURE.md")
 def test_safe_training_matches_insec():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
@@ -21,8 +18,9 @@ from repro.configs import get_smoke_config
 from repro.models import Model
 from repro.core import make_aggregator
 from repro.train.train_step import make_train_step
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("internlm2-1.8b")
 model = Model(cfg)
 toks = np.random.RandomState(0).randint(0, cfg.vocab, (4, 2, 64)).astype(np.int32)
@@ -46,9 +44,6 @@ print("SAFE_TRAIN_OK")
     assert "SAFE_TRAIN_OK" in out
 
 
-@pytest.mark.skipif(not partial_manual_supported(), reason=
-    "partial-manual shard_map (manual data + auto model) unsupported "
-    "by this jax/XLA SPMD partitioner — see ARCHITECTURE.md")
 def test_training_with_learner_failure():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
@@ -56,8 +51,9 @@ from repro.configs import get_smoke_config
 from repro.models import Model
 from repro.core import make_aggregator
 from repro.train.train_step import make_train_step
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("internlm2-1.8b")
 model = Model(cfg)
 agg = make_aggregator("safe", 4, axis="data")
@@ -81,9 +77,6 @@ print("FAILOVER_TRAIN_OK")
     assert "FAILOVER_TRAIN_OK" in out
 
 
-@pytest.mark.skipif(not partial_manual_supported(), reason=
-    "partial-manual shard_map (manual data + auto model) unsupported "
-    "by this jax/XLA SPMD partitioner — see ARCHITECTURE.md")
 def test_federated_weighted_rounds():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
@@ -91,8 +84,9 @@ from repro.configs import get_smoke_config
 from repro.models import Model
 from repro.core import make_aggregator
 from repro.train.federated import make_federated_round
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("internlm2-1.8b")
 model = Model(cfg)
 agg = make_aggregator("safe", 4, axis="data", weighted=True)
@@ -124,7 +118,8 @@ from repro.net import SafeBroker, run_federated_rounds_net
 
 n = {n}
 R = {rounds}
-mesh = jax.make_mesh((n,), ("data",))  # fully manual: works on every jax
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((n,), ("data",))
 cfg = get_smoke_config("internlm2-1.8b")
 model = Model(cfg)
 agg = make_aggregator("safe", n, axis="data", weighted=True)
@@ -198,9 +193,6 @@ def test_wire_round_delta_bit_identical(n, rounds):
     assert "WIRE_FED_BITIDENT_OK" in out
 
 
-@pytest.mark.skipif(not partial_manual_supported(), reason=
-    "partial-manual shard_map (manual data + auto model) unsupported "
-    "by this jax/XLA SPMD partitioner — see ARCHITECTURE.md")
 def test_expert_parallel_moe_matches_dense():
     # f32: in bf16 a freshly-initialized router has near-uniform probs, so
     # 1-ulp accumulation differences between batch tilings legitimately
@@ -215,7 +207,8 @@ from repro.train.flatten import is_expert_path, _path_str
 
 cfg = dataclasses.replace(get_smoke_config("qwen3-moe-235b-a22b"),
                           dtype="float32")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 model_dense = Model(cfg)
 params = model_dense.init(jax.random.key(0))
 toks = jnp.asarray(np.random.RandomState(0).randint(0, cfg.vocab, (4, 32))
