@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import KEYSTREAM
+
 # Threefry-2x32 rotation schedule (8 distinct rotations, reused over 20 rounds).
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 # Threefish key-schedule parity constant for 32-bit words.
@@ -80,13 +82,15 @@ def keystream(key: jax.Array, n: int, counter_base: jax.Array | int = 0) -> jax.
     """
     if isinstance(counter_base, (int, np.integer)):
         counter_base = np.uint32(int(counter_base) & 0xFFFFFFFF)
-    base = jnp.asarray(counter_base, jnp.uint32)
-    idx = jnp.arange(n, dtype=jnp.uint32)
-    # Counter words: (block index, lane). Two output words per block would
-    # halve PRF work; we deliberately keep 1 word/counter here for clarity —
-    # the fused Pallas kernel uses both lanes (see kernels/threefry_mask_add).
-    y0, _ = threefry2x32(key, base + idx, jnp.zeros_like(idx))
-    return y0
+    with jax.named_scope(KEYSTREAM):
+        base = jnp.asarray(counter_base, jnp.uint32)
+        idx = jnp.arange(n, dtype=jnp.uint32)
+        # Counter words: (block index, lane). Two output words per block
+        # would halve PRF work; we deliberately keep 1 word/counter here for
+        # clarity — the fused Pallas kernel uses both lanes (see
+        # kernels/threefry_mask_add).
+        y0, _ = threefry2x32(key, base + idx, jnp.zeros_like(idx))
+        return y0
 
 
 def keystream_pair_lanes(key: jax.Array, n: int, counter_base: jax.Array | int = 0) -> jax.Array:
@@ -102,10 +106,11 @@ def keystream_pair_lanes(key: jax.Array, n: int, counter_base: jax.Array | int =
     """
     if isinstance(counter_base, (int, np.integer)):
         counter_base = np.uint32(int(counter_base) & 0xFFFFFFFF)
-    base = jnp.asarray(counter_base, jnp.uint32)
-    idx = jnp.arange(n, dtype=jnp.uint32)
-    y0, y1 = threefry2x32(key, base + (idx >> 1), jnp.zeros_like(idx))
-    return jnp.where((idx & 1).astype(jnp.bool_), y1, y0)
+    with jax.named_scope(KEYSTREAM):
+        base = jnp.asarray(counter_base, jnp.uint32)
+        idx = jnp.arange(n, dtype=jnp.uint32)
+        y0, y1 = threefry2x32(key, base + (idx >> 1), jnp.zeros_like(idx))
+        return jnp.where((idx & 1).astype(jnp.bool_), y1, y0)
 
 
 def derive_key(master: jax.Array, *tags: int) -> jax.Array:

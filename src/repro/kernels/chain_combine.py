@@ -33,6 +33,7 @@ from repro.kernels.threefry_mask_add import (
     encode_block,
     pad_for_block,
 )
+from repro.obs.trace import CHAIN_COMBINE, TILE_PAD, TILE_SLICE
 
 
 def _chain_combine_kernel(scalars, cipher_ref, x_ref, o_ref, *,
@@ -61,8 +62,9 @@ def chain_combine(
     V = cipher.shape[0]
     elems = block_rows * LANE
     vpad = (-V) % elems
-    c2 = jnp.pad(cipher, (0, vpad)).reshape(-1, LANE)
-    x2 = jnp.pad(x, (0, vpad)).reshape(-1, LANE)
+    with jax.named_scope(TILE_PAD):
+        c2 = jnp.pad(cipher, (0, vpad)).reshape(-1, LANE)
+        x2 = jnp.pad(x, (0, vpad)).reshape(-1, LANE)
     nblocks = c2.shape[0] // block_rows
 
     scalars = jnp.concatenate([
@@ -71,7 +73,7 @@ def chain_combine(
         as_u32_scalar(counter_base).reshape(1),
     ])
 
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(_chain_combine_kernel, scale_bits=scale_bits,
                           block_rows=block_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -85,8 +87,12 @@ def chain_combine(
         ),
         out_shape=jax.ShapeDtypeStruct(c2.shape, jnp.uint32),
         interpret=interpret,
-    )(scalars, c2, x2)
-    return out.reshape(-1)[:V]
+        name=CHAIN_COMBINE,
+    )
+    with jax.named_scope(CHAIN_COMBINE):
+        out = kernel(scalars, c2, x2)
+    with jax.named_scope(TILE_SLICE):
+        return out.reshape(-1)[:V]
 
 
 def _chain_combine_batched_kernel(scalars, cipher_ref, x_ref, o_ref, *,

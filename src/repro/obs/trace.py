@@ -12,13 +12,31 @@ The ring buffer bounds memory by construction: a long-lived broker
 under heavy load keeps the most recent ``capacity`` spans and silently
 drops the oldest (``dropped`` counts them, so an exporter can tell a
 quiet broker from a wrapped one).
+
+The device path is named differently: its spans are XLA's own. The
+program wraps the chain hop's kernel, its tile copies and the keystream
+in ``jax.named_scope`` under the names below, which XLA writes into each
+operation's ``op_name`` metadata, so the device trace's operations carry
+them on the device's own clock. The names live here as plain strings, so
+the program and whatever reads its traces share one definition and this
+package stays free of jax.
 """
 from __future__ import annotations
 
 from collections import deque
 from typing import Deque, List, Optional
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["Span", "Tracer", "TILE_PAD", "TILE_SLICE", "KEYSTREAM",
+           "CHAIN_COMBINE"]
+
+#: the hop wrapper's pad of its operands to whole (rows, LANE) tiles
+TILE_PAD = "tile_pad"
+#: the hop wrapper's reshape and slice of its output back to V words
+TILE_SLICE = "tile_slice"
+#: the jnp Threefry keystream (``crypto.prf``)
+KEYSTREAM = "keystream"
+#: the chain hop's Pallas kernel: its ``pallas_call``'s ``name=`` and scope
+CHAIN_COMBINE = "chain_combine"
 
 
 class Span:

@@ -12,6 +12,7 @@ one process may load the TPU library at a time, so a file that loaded it
 while being imported would break collection under several test workers.
 """
 import os
+import re
 import sys
 
 import jax
@@ -29,6 +30,7 @@ from repro.kernels.chain_combine import (chain_combine,  # noqa: E402
                                          chain_combine_batched)
 from repro.kernels.threefry_mask_add import mask_add  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
+from repro.obs.trace import CHAIN_COMBINE, TILE_PAD, TILE_SLICE  # noqa: E402
 from repro.serve import AggregationEngine  # noqa: E402
 
 #: every parameter of internvl2-1b (chip_smoke.update_words() computes it)
@@ -94,6 +96,24 @@ def test_hop_kernels_compile_at_full_update(one_chip, kernel):
                                                   interpret=False),
             u32, f32, key, key, ctr)
     assert chip_smoke.planned_bytes(compiled) < HBM_BYTES
+
+
+def test_hop_names_survive_the_tpu_compile(one_chip):
+    """The names a device trace's readers look for: after the TPU
+    compile, the hop's Pallas call carries its kernel's scope and the
+    copies around it carry ``tile_pad`` and ``tile_slice``."""
+    V = 3 * 8192 + 5
+    key = _spec((2,), jnp.uint32, one_chip)
+    text = _compile_kernel(
+        lambda c, x, ki, ko, b: chain_combine(c, x, ki, ko, b,
+                                              interpret=False),
+        _spec((V,), jnp.uint32, one_chip), _spec((V,), jnp.float32, one_chip),
+        key, key, _spec((), jnp.uint32, one_chip)).as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls and all(f"/{CHAIN_COMBINE}/" in line for line in calls)
+    for scope in (TILE_PAD, TILE_SLICE):
+        assert re.search(rf'op_name="[^"]*/{scope}/[^"]*"', text), scope
 
 
 @pytest.mark.parametrize("kernel", ["chain_combine_batched", "bon_mask"])
