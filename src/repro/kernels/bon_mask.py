@@ -27,6 +27,7 @@ from repro.kernels.threefry_mask_add import (
     as_u32_scalar,
     DEFAULT_BLOCK_ROWS,
     encode_block,
+    lane_view,
     pad_for_block,
 )
 
@@ -59,10 +60,7 @@ def bon_mask(
     """x: f32[V]; keys: uint32[m, 2]; signs: int32[m] (+1/−1) -> uint32[V]."""
     V = x.shape[0]
     m = keys.shape[0]
-    elems = block_rows * LANE
-    vpad = (-V) % elems
-    x2 = jnp.pad(x, (0, vpad)).reshape(-1, LANE)
-    nblocks = x2.shape[0] // block_rows
+    x2, nblocks = lane_view(x, block_rows)
 
     # scalar layout: [k0_j, k1_j, sign_j]*m + [base]; sign encoded 1/0
     packed = jnp.concatenate([

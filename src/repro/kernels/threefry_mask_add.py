@@ -7,13 +7,20 @@ add); this kernel does one read of ``x`` and one write of the masked
 ciphertext — the pad never touches HBM.
 
 TPU adaptation notes (DESIGN.md §4):
-  * masking is element-wise VPU work — the roofline is HBM bandwidth, so
-    fusion is the whole optimization;
+  * masking is element-wise VPU work, and the Threefry rounds set the
+    bound, not HBM: on a TPU v5e the hop kernel moves its 12 B/word at
+    about a quarter of HBM bandwidth (PERF.md §5). Fusion still saves the
+    pad's HBM round trips;
   * blocks are (block_rows, 128): lane-dim 128 matches the VPU/VREG lane
     width, block_rows a multiple of 8 for f32 sublane packing;
+  * the wrappers run the grid over a (rows, 128) view of the update
+    (``lane_view``): V is padded only to whole rows of 128 words, so an
+    update of a multiple of 128 words is viewed in place, with no copy
+    (a flat 32-bit array and its (rows, 128) view share one tiled
+    layout on the TPU);
   * each element evaluates the full Threefry-2x32 block for its counter
     and selects its lane — lane-redundant (2× VPU flops) but gather-free
-    and layout-preserving; the VPU has headroom at 0.36 B/flop.
+    and layout-preserving.
 """
 from __future__ import annotations
 
@@ -38,6 +45,24 @@ def as_u32_scalar(x):
         return jnp.asarray(np.uint32(int(x) & 0xFFFFFFFF))
     return jnp.asarray(x, jnp.uint32)
 DEFAULT_BLOCK_ROWS = 64  # 64×128 u32 = 32 KiB / block operand — fits VMEM easily
+
+
+def lane_view(a: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
+    """``a[..., V]`` as ``[..., rows, LANE]``, and the grid's block count
+    over those rows, ``cdiv(rows, block_rows)``.
+
+    V is padded only to whole rows, not to whole blocks: the grid's last
+    block may run past ``rows``, where Pallas reads don't-care values
+    and drops the writes. A word's keystream depends only on its flat
+    index, so the words kept are the same. When V is a multiple of
+    ``LANE`` the pad has zero width and the view of a flat ``a`` is a
+    bitcast; otherwise the pad copies ``a`` and the caller's slice back
+    to V words copies its output.
+    """
+    vpad = (-a.shape[-1]) % LANE
+    a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, vpad)])
+    view = a.reshape(*a.shape[:-1], -1, LANE)
+    return view, pl.cdiv(view.shape[-2], block_rows)
 
 
 def _rotl32(x, d: int):
@@ -101,14 +126,12 @@ def mask_add(
 ) -> jax.Array:
     """out[i] = encode(x[i]) + PRF(key, base + i)  (mod 2^32), fused.
 
-    x: f32[V] (any V — padded internally to a whole tile grid).
-    key: uint32[2]. Returns uint32[V].
+    x: f32[V], any V: viewed as (rows, 128) in place when V is a multiple
+    of 128, else padded to the next multiple (``lane_view``).
+    key: uint32[2]. Returns uint32[V], a new buffer.
     """
     V = x.shape[0]
-    elems = block_rows * LANE
-    vpad = (-V) % elems
-    x2 = jnp.pad(x, (0, vpad)).reshape(-1, LANE)
-    nblocks = x2.shape[0] // block_rows
+    x2, nblocks = lane_view(x, block_rows)
 
     scalars = jnp.concatenate(
         [jnp.asarray(key, jnp.uint32).reshape(2),

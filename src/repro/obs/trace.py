@@ -29,7 +29,8 @@ from typing import Deque, List, Optional
 __all__ = ["Span", "Tracer", "TILE_PAD", "TILE_SLICE", "KEYSTREAM",
            "CHAIN_COMBINE"]
 
-#: the hop wrapper's pad of its operands to whole (rows, LANE) tiles
+#: the hop wrapper's (rows, LANE) view of its operands: a pad to whole
+#: rows of LANE words where V is not a multiple of LANE, else nothing
 TILE_PAD = "tile_pad"
 #: the hop wrapper's reshape and slice of its output back to V words
 TILE_SLICE = "tile_slice"

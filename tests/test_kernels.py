@@ -16,7 +16,16 @@ from repro.kernels.ref import (bon_mask_ref, chain_combine_batched_ref,
                                chain_combine_ref, mask_add_ref)
 from repro.kernels.threefry_mask_add import mask_add as raw_mask_add
 
-SHAPES = [1, 5, 127, 128, 129, 1000, 8192, 100_001]
+#: the kinds of V the wrappers lay out differently, each viewed in place
+#: as (rows, 128) but the last: whole (8, 128) tiles whose grid ends in a
+#: ragged block, rows that end in a part of a tile (as internvl2-1b's
+#: published update of 498,263,808 words does), a whole number of
+#: (64, 128) blocks, and a V padded to the next 128
+RAGGED, PART_TILE, WHOLE, UNALIGNED = (3 * 8192 + 5 * 1024,
+                                       3 * 8192 + 6 * 128, 4 * 8192,
+                                       3 * 8192 + 5)
+LAYOUTS = [RAGGED, PART_TILE, WHOLE, UNALIGNED]
+SHAPES = [1, 5, 127, 128, 129, 1000, 8192, 100_001] + LAYOUTS
 
 
 @pytest.mark.parametrize("V", SHAPES)
@@ -59,7 +68,7 @@ def test_mask_add_scale_bits(scale_bits):
         np.asarray(mask_add_ref(x, key, 0, scale_bits=scale_bits)))
 
 
-@pytest.mark.parametrize("V", [7, 640, 9000])
+@pytest.mark.parametrize("V", [7, 640, 9000] + LAYOUTS)
 def test_chain_combine(V):
     rng = np.random.RandomState(V)
     cipher = jnp.asarray(rng.randint(0, 2**32, V, dtype=np.uint64).astype(np.uint32))
@@ -71,7 +80,8 @@ def test_chain_combine(V):
         np.asarray(chain_combine_ref(cipher, x, kin, kout, 9)))
 
 
-@pytest.mark.parametrize("S,V", [(1, 128), (3, 1000), (8, 257)])
+@pytest.mark.parametrize("S,V", [(1, 128), (3, 1000), (8, 257)]
+                         + [(2, V) for V in LAYOUTS])
 def test_chain_combine_batched(S, V):
     """Session-batched kernel == oracle (exact, per-session keys/counters
     delivered via scalar prefetch)."""
@@ -112,6 +122,30 @@ def test_chain_combine_batched_matches_single_calls():
                                      bases[s])))
 
 
+@pytest.mark.parametrize("V", LAYOUTS)
+def test_chain_combine_donated_matches_kept(V):
+    """The hop's output takes the incoming cipher's buffer: under a jit
+    that donates the cipher it returns the same words as under one that
+    keeps it, and the kept cipher is left as it was."""
+    rng = np.random.RandomState(V)
+    cipher_np = rng.randint(0, 2**32, V, dtype=np.uint64).astype(np.uint32)
+    x = jnp.asarray(rng.uniform(-50, 50, V).astype(np.float32))
+    kin = jnp.array([11, 22], jnp.uint32)
+    kout = jnp.array([33, 44], jnp.uint32)
+
+    def hop(c, x):
+        return chain_combine(c, x, kin, kout, 9)
+
+    cipher = jnp.asarray(cipher_np)
+    kept = np.asarray(jax.jit(hop)(cipher, x))
+    np.testing.assert_array_equal(np.asarray(cipher), cipher_np)
+    donated = np.asarray(jax.jit(hop, donate_argnums=0)(
+        jnp.asarray(cipher_np), x))
+    np.testing.assert_array_equal(donated, kept)
+    np.testing.assert_array_equal(
+        kept, np.asarray(chain_combine_ref(cipher_np, x, kin, kout, 9)))
+
+
 def test_chain_combine_roundtrip_semantics():
     """A full 4-hop kernel chain equals the sum of the inputs (masks and
     pads cancel) — the kernel-level version of the protocol test."""
@@ -134,9 +168,10 @@ def test_chain_combine_roundtrip_semantics():
                                np.asarray(sum(vals)), atol=n / 2**16 + 1e-4)
 
 
-@pytest.mark.parametrize("m", [1, 2, 8, 15])
-def test_bon_mask(m):
-    V = 2000
+@pytest.mark.parametrize("m,V", [pytest.param(m, 2000, id=str(m))
+                                 for m in (1, 2, 8, 15)]
+                         + [(3, V) for V in LAYOUTS])
+def test_bon_mask(m, V):
     rng = np.random.RandomState(m)
     x = jnp.asarray(rng.uniform(-50, 50, V).astype(np.float32))
     keys = jnp.asarray(rng.randint(0, 2**32, (m, 2), dtype=np.uint64)

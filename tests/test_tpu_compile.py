@@ -35,6 +35,10 @@ from repro.serve import AggregationEngine  # noqa: E402
 
 #: every parameter of internvl2-1b (chip_smoke.update_words() computes it)
 V_FULL = 493_753_344
+#: internvl2-1b's published update: V_FULL plus the q/k/v biases and the
+#: mlp1 projector. A multiple of 128 words but not of 1024, so its
+#: (rows, 128) view ends in a part of an (8, 128) tile
+V_PUBLISHED = 498_263_808
 HBM_BYTES = 16 * 2**30
 
 
@@ -96,6 +100,51 @@ def test_hop_kernels_compile_at_full_update(one_chip, kernel):
                                                   interpret=False),
             u32, f32, key, key, ctr)
     assert chip_smoke.planned_bytes(compiled) < HBM_BYTES
+
+
+def _full_size_copies(text: str, words: int) -> list[str]:
+    """Each pad, slice or copy in the compiled HLO ``text`` that makes a
+    buffer of at least ``words``, flat or as (rows, 128)."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= [a-z0-9]+\[(\d+)(,128)?\]\S* (pad|slice|copy)\(",
+                      line)
+        if m and int(m[1]) * (128 if m[2] else 1) >= words:
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("words", [V_FULL, V_PUBLISHED])
+def test_donated_hop_is_the_kernel_alone_at_full_update(one_chip, words):
+    """At a full update (a multiple of 128 words, not of a 64-row block)
+    a hop that donates its cipher, as a chain hop does, runs the Pallas
+    call on views of its operands and writes over the cipher: nothing of
+    update size is padded, sliced or copied, and it plans no temporary
+    buffer."""
+    key = _spec((2,), jnp.uint32, one_chip)
+    compiled = jax.jit(
+        lambda c, x, ki, ko, b: chain_combine(c, x, ki, ko, b,
+                                              interpret=False),
+        donate_argnums=0).lower(
+            _spec((words,), jnp.uint32, one_chip),
+            _spec((words,), jnp.float32, one_chip),
+            key, key, _spec((), jnp.uint32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _full_size_copies(text, words)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("words", [V_FULL, V_PUBLISHED])
+def test_mask_add_pads_nothing_at_full_update(one_chip, words):
+    """The initiator's kernel reads the update through a view too; the
+    only pad left is the key scalars' packing."""
+    compiled = _compile_kernel(
+        lambda x, k, c: mask_add(x, k, c, interpret=False),
+        _spec((words,), jnp.float32, one_chip),
+        _spec((2,), jnp.uint32, one_chip), _spec((), jnp.uint32, one_chip))
+    assert not _full_size_copies(compiled.as_text(), words)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 def test_hop_names_survive_the_tpu_compile(one_chip):
