@@ -276,14 +276,14 @@ def train_phase(mesh, cfg, *, steps: int, batch_per_learner: int,
                for s in range(steps)]
     losses, step_s = {}, {}
     for mode in ("safe", "insec"):
-        bundle = make_train_step(model, make_aggregator(mode, n, axis="data"),
-                                 mesh, lr=lr)
+        agg = make_aggregator(mode, n, axis="data")
+        bundle = make_train_step(model, agg, mesh, lr=lr)
         state = bundle.init_state_fn(model.init(jax.random.key(seed)))
         losses[mode], times = [], []
         for s in range(steps):
             t0 = time.perf_counter()
             state, m = bundle.step_fn(state, batches[s],
-                                      counter=s * (bundle.padded_size + 2))
+                                      agg.reserve_round(bundle.round_words))
             losses[mode].append(float(m["loss"]))
             times.append(time.perf_counter() - t0)
         step_s[mode] = times

@@ -28,6 +28,7 @@ SMOKE = bool(os.environ.get("SAFE_SMOKE"))
 import jax  # noqa: E402
 
 from repro.configs import get_smoke_config  # noqa: E402
+from repro.core.session import RoundCursor  # noqa: E402
 from repro.data import make_federated_batches  # noqa: E402
 from repro.models import Model  # noqa: E402
 from repro.net import SafeBroker, run_federated_round_net  # noqa: E402
@@ -59,6 +60,9 @@ def main():
           f"({wf.payload_words * 4 / 1e6:.1f} MB/hop, "
           f"{-(-wf.payload_words // CHUNK_WORDS)} chunks)")
 
+    # fresh counter space every round; refuses rather than wraps
+    cursor = RoundCursor(wf.words_per_round(weighted=True))
+
     async def train(params):
         broker = SafeBroker(progress_timeout=0.5, monitor_interval=0.1,
                             aggregation_timeout=60.0)
@@ -68,7 +72,7 @@ def main():
                 failed = (3,) if r >= FAIL_AT else ()
                 params, res = await run_federated_round_net(
                     params, wf.local_fns, wf.apply_fn, addr,
-                    weights=weights, counter=r * (wf.payload_words + 1),
+                    weights=weights, counter=cursor.next_round(),
                     failed_nodes=failed, chunk_words=CHUNK_WORDS)
                 losses = [wf.last_losses[n] for n in sorted(wf.last_losses)
                           if n not in failed]

@@ -39,9 +39,11 @@ def main():
     log = MetricsLogger(print_every=5)
     steps = 6 if os.environ.get("SAFE_SMOKE") else 30
     for step in range(steps):
+        # fresh pads every step: (key epoch, counter base) from the
+        # aggregator, which rotates the keys when an epoch's space runs out
         state, metrics = bundle.step_fn(
             state, dataset[step % len(dataset)],
-            counter=step * (bundle.padded_size + 2))
+            aggregator.reserve_round(bundle.round_words))
         log.log(step, loss=metrics["loss"], grad=metrics["grad_scale"])
     print("final loss:", float(metrics["loss"]))
 

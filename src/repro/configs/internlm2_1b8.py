@@ -2,6 +2,9 @@
 
 [dense] 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92544
 [arXiv:2403.17297]. Pure global attention -> long_500k skipped.
+The published ``config.json`` of ``internlm/internlm2-1_8b`` unties the
+output head (``tie_word_embeddings: false``) and sets ``rms_norm_eps``
+1e-5 and ``rope_theta`` 1e6.
 """
 from repro.models.config import ModelConfig
 
@@ -17,4 +20,6 @@ CONFIG = ModelConfig(
     head_dim=128,
     pattern=("global",),
     rope_theta=1000000.0,
+    tie_embeddings=False,
+    norm_eps=1e-5,
 )

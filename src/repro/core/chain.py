@@ -39,11 +39,19 @@ import jax.numpy as jnp
 from repro.crypto.fixedpoint import FixedPointCodec
 from repro.crypto.prf import derive_key, derive_pair_key, keystream_pair_lanes
 from repro.core.types import ChainConfig, RoundKeys
+from repro.obs.trace import CHAIN_HOP
 from repro.topology import elect_initiator_local
 
 # Domain-separation tags for derive_key.
 _TAG_INITIATOR_MASK = 0x52  # 'R'
 _TAG_HOP_PAD = 0x50  # 'P'
+
+
+def _ring_hop(x: jax.Array, axis: str, perm) -> jax.Array:
+    """One hop of the learner ring: every rank sends ``x`` to its
+    successor (a device trace names it ``chain_hop``)."""
+    with jax.named_scope(CHAIN_HOP):
+        return jax.lax.ppermute(x, axis, perm)
 
 
 def _hop_pads(keys: RoundKeys, rank, topo, nwords: int, use_pads: bool):
@@ -142,7 +150,7 @@ def chain_aggregate_sequential(
     perm = topo.ring_permutation()
 
     def hop(t, x):
-        x = jax.lax.ppermute(x, axis, perm)
+        x = _ring_hop(x, axis, perm)
         # The rank t local-steps after the initiator combines now:
         active = rank == g0 + (init_local + t) % m
         delta = ev - pad_in + pad_out  # decrypt, add local, re-encrypt
@@ -155,7 +163,7 @@ def chain_aggregate_sequential(
         x = jax.lax.fori_loop(1, m, hop, x)
 
     # Final hop back to the initiator, which unmasks.
-    x = jax.lax.ppermute(x, axis, perm)
+    x = _ring_hop(x, axis, perm)
     total = x - pad_in - R  # Σ enc(x_i) over the group, exact in Z/2^32Z
 
     count = jnp.sum(group_alive)
@@ -238,7 +246,7 @@ def chain_aggregate_pipelined(
     c = ev[s] + R_own + pads_out[s]
 
     def step(t, c):
-        c = jax.lax.ppermute(c, axis, perm)
+        c = _ring_hop(c, axis, perm)
         s = (lrank - t) % m  # segment id now resident on this rank
         return c - pads_in[s] + ev[s] + pads_out[s]
 
@@ -249,7 +257,7 @@ def chain_aggregate_pipelined(
         c = jax.lax.fori_loop(1, m, step, c)
 
     # One final hop returns segment lrank to its initiator, which unmasks.
-    c = jax.lax.ppermute(c, axis, perm)
+    c = _ring_hop(c, axis, perm)
     total_seg = c - pads_in[lrank] - R_own  # Σ_i enc(x_i)[segment lrank]
 
     # Republish: all_gather the unmasked segment sums (aggregates are
@@ -323,7 +331,7 @@ def chain_aggregate_batched(
     Args:
       values: f32[S, V] — this rank's vector for each session.
       prov_seeds: uint32[S, 2] — per-session *derived* provisioning key
-        (the output of ``derive_key(seed_words, domain)``, i.e. exactly
+        (the output of ``derive_key(seed_words, domain, epoch)``, i.e. exactly
         what ``make_round_keys`` puts in ``RoundKeys.provisioning_seed``).
       learner_seeds: uint32[S, 2] — per-session per-rank private seed
         (``RoundKeys.learner_seed``).

@@ -157,11 +157,11 @@ def train_spec(arch_cfg: ModelConfig, mesh: Mesh, shape: dict,
     else:
         prefix = jax.ShapeDtypeStruct((1,), jnp.float32)
     weights = jax.ShapeDtypeStruct((n,), jnp.float32)
-    counter = jax.ShapeDtypeStruct((), jnp.uint32)
+    epoch = counter = jax.ShapeDtypeStruct((), jnp.uint32)
     alive = jax.ShapeDtypeStruct((n,), jnp.float32)
 
     args = (params_in, flat, flat, flat, fstep, ep_state, sec_state, toks,
-            prefix, weights, counter, alive)
+            prefix, weights, epoch, counter, alive)
     return DryrunSpec(fn=bundle.jit_fn, args=args,
                       description=f"train_step n={n} pods={pods} B_l={B_l} "
                                   f"agg={aggregator_mode}"

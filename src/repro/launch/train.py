@@ -87,10 +87,12 @@ def main():
             alive = np.ones(args.learners, np.float32)
             if dead and (args.fail_at_step < 0 or r >= args.fail_at_step):
                 alive[list(dead)] = 0.0
+            slot = agg.reserve_round(bundle.round_words)
             params, m = bundle.round_fn(
-                params, jnp.asarray(toks), weights=jnp.asarray(gb["weights"]),
-                counter=r * 2**20, alive=jnp.asarray(alive))
-            log.log(r, **{k: float(v) for k, v in m.items()})
+                params, jnp.asarray(toks), slot,
+                weights=jnp.asarray(gb["weights"]), alive=jnp.asarray(alive))
+            log.log(r, key_epoch=slot.epoch, counter=slot.base,
+                    **{k: float(v) for k, v in m.items()})
     else:
         bundle = make_train_step(model, agg, mesh, lr=args.lr)
         state = bundle.init_state_fn(params)
@@ -98,21 +100,25 @@ def main():
         if args.ckpt_dir and (s := latest_step(args.ckpt_dir)) is not None:
             state, extra = restore_checkpoint(args.ckpt_dir, s, state)
             start = int(extra.get("step", s))
+            agg.resume(extra["key_epoch"], extra["counter_next"])
             print(f"resumed from step {start}")
         for step in range(start, args.steps):
             gb = stream.global_batch(step)
             alive = np.ones(args.learners, np.float32)
             if dead and (args.fail_at_step < 0 or step >= args.fail_at_step):
                 alive[list(dead)] = 0.0
-            state, m = bundle.step_fn(
-                state, jnp.asarray(gb["tokens"]),
-                counter=(step % 2000) * (bundle.padded_size + 2),
-                alive=jnp.asarray(alive))
+            slot = agg.reserve_round(bundle.round_words)
+            state, m = bundle.step_fn(state, jnp.asarray(gb["tokens"]), slot,
+                                      alive=jnp.asarray(alive))
             log.log(step, loss=float(m["loss"]),
-                    grad_scale=float(m["grad_scale"]))
+                    grad_scale=float(m["grad_scale"]),
+                    key_epoch=slot.epoch, counter=slot.base)
             if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                # the key state after the steps up to the next checkpoint:
+                # a resume starts past every pad this run can still use
+                keys = agg.key_state_after(args.ckpt_every, bundle.round_words)
                 save_checkpoint(args.ckpt_dir, step + 1, state,
-                                extra={"step": step + 1})
+                                extra={"step": step + 1, **keys})
     print(f"done in {time.time() - t0:.1f}s")
 
 

@@ -144,11 +144,15 @@ class Model:
         params["blocks"] = blocks
         if shared is not None:
             params["shared_attn"] = shared
-        # store weight matrices in the compute dtype (bf16); norms/scalars
-        # stay f32 (the f32 master lives in the ZeRO-1 flat vector)
+        # store weight matrices in the compute dtype (bf16); norm gains and
+        # other vectors stay f32 (the f32 master lives in the ZeRO-1 flat
+        # vector). A block's leaves carry a leading stacking dim.
         if cfg.dtype == "bfloat16":
-            params = jax.tree.map(
-                lambda x: x.astype(jnp.bfloat16) if x.ndim >= 2 else x, params)
+            def store(path, x):
+                stacked = getattr(path[0], "key", None) == "blocks"
+                return (x.astype(jnp.bfloat16) if x.ndim - stacked >= 2
+                        else x)
+            params = jax.tree_util.tree_map_with_path(store, params)
         return params
 
     # -- forward (train / prefill) -------------------------------------------

@@ -45,6 +45,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.session import RoundCursor
 from repro.net.broker import SafeBroker
 from repro.net.client import (
     PersistentNetSession,
@@ -306,7 +307,9 @@ async def run_slo_load(
         busy = 0
         icpt = (make_wan_interceptor(wan_profile, seed=wan_seed + t)
                 if wan_profile else None)
+        cursor = RoundCursor(tV + 1)
         for r in range(rounds_per_tenant):
+            base = cursor.next_round()
             t0 = time.perf_counter()
             # stream=False pins chunked tenants to the buffered chunk
             # plane: these profiles exist to put admission control
@@ -316,7 +319,7 @@ async def run_slo_load(
             res = await run_safe_round_net(
                 vals, addr, subgroups=sg,
                 provisioning_seed=0xC0FFEE + t,
-                learner_master=0x5EED + 17 * t, counter=r * (tV + 1),
+                learner_master=0x5EED + 17 * t, counter=base,
                 chunk_words=cw,
                 stream=False if cw is not None else None,
                 interceptor=icpt, timeout_scale=timeout_scale)
@@ -332,7 +335,7 @@ async def run_slo_load(
             if bit_identical:
                 sim = run_safe_round(
                     vals, subgroups=sg, provisioning_seed=0xC0FFEE + t,
-                    learner_master=0x5EED + 17 * t, counter=r * (tV + 1))
+                    learner_master=0x5EED + 17 * t, counter=base)
                 if not np.array_equal(sim.average, res.average):
                     raise RuntimeError(
                         f"tenant {t} round {r}: wire average not "
@@ -516,13 +519,14 @@ async def _drive_tenants(addr: Addr, tenant_indices: Sequence[int], *,
             finally:
                 await sess.close()
             return lats
+        cursor = RoundCursor(V + 1)
         for r in range(rounds_per_tenant):
             t0 = time.perf_counter()
             res = await run_safe_round_net(
                 tenant_vals[t], addr,
                 provisioning_seed=0xC0FFEE + t,
                 learner_master=0x5EED + 17 * t,
-                counter=r * (V + 1),
+                counter=cursor.next_round(),
                 interceptor=ic, chunk_words=chunk_words,
                 prefetch_depth=prefetch_depth)
             lats.append(time.perf_counter() - t0)
@@ -1080,7 +1084,7 @@ async def run_shard_failover_load(
         await loop.run_in_executor(None, proc.join, 10.0)
         killed.set()
 
-    def check(t: int, r: int, res, vals) -> None:
+    def check(t: int, r: int, base: int, res, vals) -> None:
         got = res.stats["aggregation_total"]
         if got != 4 * n:
             raise RuntimeError(
@@ -1088,7 +1092,7 @@ async def run_shard_failover_load(
                 f"§5 closed form says {4 * n}")
         sim = run_safe_round(
             vals, provisioning_seed=0xC0FFEE + t,
-            learner_master=0x5EED + 17 * t, counter=r * (V + 1))
+            learner_master=0x5EED + 17 * t, counter=base)
         if not np.array_equal(sim.average, res.average):
             raise RuntimeError(
                 f"tenant {t} round {r}: round not bit-identical to "
@@ -1100,9 +1104,13 @@ async def run_shard_failover_load(
             addr, n, provisioning_seed=0xC0FFEE + t,
             learner_master=0x5EED + 17 * t, words_per_round=V + 1)
         await sess.open()
+        # the session's own cursor reserves the same bases in the same
+        # order; this twin hands them to the replay and the check
+        cursor = RoundCursor(V + 1)
         stranded = False
         try:
             for r in range(rounds_per_tenant):
+                base = cursor.next_round()
                 if not stranded:
                     try:
                         res = await sess.run_round(vals)
@@ -1118,8 +1126,8 @@ async def run_shard_failover_load(
                         vals, addr,
                         provisioning_seed=0xC0FFEE + t,
                         learner_master=0x5EED + 17 * t,
-                        counter=r * (V + 1))
-                check(t, r, res, vals)
+                        counter=base)
+                check(t, r, base, res, vals)
                 if r == kill_after_round:
                     barrier_done[t].set()
                     await killed.wait()

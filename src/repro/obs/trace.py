@@ -14,12 +14,12 @@ drops the oldest (``dropped`` counts them, so an exporter can tell a
 quiet broker from a wrapped one).
 
 The device path is named differently: its spans are XLA's own. The
-program wraps the chain hop's kernel, its tile copies and the keystream
-in ``jax.named_scope`` under the names below, which XLA writes into each
-operation's ``op_name`` metadata, so the device trace's operations carry
-them on the device's own clock. The names live here as plain strings, so
-the program and whatever reads its traces share one definition and this
-package stays free of jax.
+program wraps the chain hop's kernel, its tile copies, the keystream and
+the parts of the train step in ``jax.named_scope`` under the names
+below, which XLA writes into each operation's ``op_name`` metadata, so
+the device trace's operations carry them on the device's own clock. The
+names live here as plain strings, so the program and whatever reads its
+traces share one definition and this package stays free of jax.
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ from collections import deque
 from typing import Deque, List, Optional
 
 __all__ = ["Span", "Tracer", "TILE_PAD", "TILE_SLICE", "KEYSTREAM",
-           "CHAIN_COMBINE"]
+           "CHAIN_COMBINE", "FWD_BWD", "SAFE_CHAIN", "CHAIN_HOP", "ZERO1"]
 
 #: the hop wrapper's (rows, LANE) view of its operands: a pad to whole
 #: rows of LANE words where V is not a multiple of LANE, else nothing
@@ -38,6 +38,15 @@ TILE_SLICE = "tile_slice"
 KEYSTREAM = "keystream"
 #: the chain hop's Pallas kernel: its ``pallas_call``'s ``name=`` and scope
 CHAIN_COMBINE = "chain_combine"
+#: the train step's forward and backward pass (``train.train_step``)
+FWD_BWD = "fwd_bwd"
+#: the train step's secure aggregation of the gradient: encode, pads,
+#: hops, unmask, decode and the broadcast of the mean
+SAFE_CHAIN = "safe_chain"
+#: each ``ppermute`` of the learner ring (``core.chain``)
+CHAIN_HOP = "chain_hop"
+#: the train step's ZeRO-1 slice update and the all-gather of parameters
+ZERO1 = "zero1"
 
 
 class Span:

@@ -86,10 +86,10 @@ class AggregationEngine:
             vals = vals.reshape(S, self.V)
             rank = jax.lax.axis_index(cfg.axis)
             # per-session key derivation — the exact make_round_keys
-            # chain (domain 0), vmapped over the session dim
-            prov_d = jax.vmap(lambda w: derive_key(w, 0))(prov_w)
+            # chain (domain 0, key epoch 0), vmapped over the session dim
+            prov_d = jax.vmap(lambda w: derive_key(w, 0, 0))(prov_w)
             learner_d = jax.vmap(
-                lambda w: derive_key(derive_key(w, 0), rank))(master_w)
+                lambda w: derive_key(derive_key(w, 0, 0), rank))(master_w)
             w_r = wts[:, rank] if cfg.weighted else None
             return chain_aggregate_batched(
                 vals, prov_d, learner_d, ctrs, cfg, alive,

@@ -45,8 +45,12 @@ from repro.train.loss import next_token_loss
 
 @dataclasses.dataclass
 class FederatedBundle:
+    """``round_fn(params, tokens, reservation, weights=None, alive=None)
+    -> (params, metrics)``; ``reservation`` is the ``(epoch, base)`` that
+    ``aggregator.reserve_round(round_words)`` returned for this round."""
     round_fn: Any
     init_state_fn: Any
+    round_words: int  # counter words one round reserves
 
 
 def make_local_update(
@@ -121,7 +125,7 @@ def make_federated_round(
     local_update = make_local_update(model, local_steps=local_steps,
                                      local_lr=local_lr)
 
-    def per_rank_round(params, tokens, weights, counter, alive):
+    def per_rank_round(params, tokens, weights, epoch, counter, alive):
         # tokens: [1, local_steps, B_l, S] for this learner
         tokens = tokens.reshape(tokens.shape[1:])
         my_w = weights[jax.lax.axis_index(learner_axis)]
@@ -129,7 +133,7 @@ def make_federated_round(
         delta, loss_mean = local_update(params, tokens)
         # §5.6: weighted secure mean of deltas; weights stay private
         avg_delta = aggregator.aggregate(delta, counter, alive=alive,
-                                         weights=my_w)
+                                         weights=my_w, epoch=epoch)
         out_params = apply_delta(params, avg_delta)
         metrics = {
             "local_loss": jax.lax.pmean(loss_mean, learner_axis),
@@ -143,23 +147,28 @@ def make_federated_round(
     batch_spec = P((pod_axis, learner_axis) if pod_axis else learner_axis)
     shard_fn = jax.shard_map(
         per_rank_round, mesh=mesh,
-        in_specs=(P(), batch_spec, P(), P(), P()),
+        in_specs=(P(), batch_spec, P(), P(), P(), P()),
         out_specs=(P(), P()),
         axis_names=frozenset(manual), check_vma=False)
     jit_fn = jax.jit(shard_fn, donate_argnums=(0,))
 
-    def round_fn(params, tokens, weights=None, counter=0, alive=None):
+    def round_fn(params, tokens, reservation, weights=None, alive=None):
+        epoch, counter = reservation
         if weights is None:
             weights = jnp.ones((n,), jnp.float32)
         if alive is None:
             alive = jnp.ones((n,), jnp.float32)
         with jax.set_mesh(mesh):
             params, metrics = jit_fn(params, tokens, weights,
-                                     jnp.asarray(counter, jnp.uint32), alive)
+                                     np.uint32(epoch), np.uint32(counter),
+                                     alive)
         return params, jax.tree.map(np.asarray, metrics)
 
-    return FederatedBundle(round_fn=round_fn,
-                           init_state_fn=lambda p: p)
+    # the flat delta, plus the weight word of a weighted mean
+    payload = tree_size(jax.eval_shape(model.init, jax.random.key(0)))
+    return FederatedBundle(round_fn=round_fn, init_state_fn=lambda p: p,
+                           round_words=payload + (
+                               1 if aggregator.cfg.weighted else 0))
 
 
 @dataclasses.dataclass
@@ -182,8 +191,8 @@ class WireFederated:
         """Counter words one aggregation round consumes (the weighted
         payload appends one weight word) — what a persistent session's
         :class:`~repro.core.session.RoundCursor` must advance by, and
-        the per-round stride the in-SPMD plane's ``counter=`` must match
-        for cross-plane bit-parity."""
+        the in-SPMD plane's ``FederatedBundle.round_words`` for the
+        same aggregator."""
         return self.payload_words + (1 if weighted else 0)
 
 

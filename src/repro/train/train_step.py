@@ -25,10 +25,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.aggregators import SecureAggregator
 from repro.models.transformer import Model
+from repro.obs.trace import FWD_BWD, SAFE_CHAIN, ZERO1
 from repro.optim.adamw import AdamW, FlatAdamW
 from repro.train.flatten import (
     combine_trees,
@@ -41,15 +42,39 @@ from repro.train.flatten import (
 from repro.train.loss import next_token_loss
 
 
+def initiator_rotation(epoch, counter, round_words: int, n: int):
+    """The initiator's offset for the step reserved at ``(epoch, counter)``
+    (uint32, traced or not): the step's running index mod ``n``.
+
+    Each key epoch opens at counter 0 and holds ``2**32 // round_words``
+    steps, so the running index is ``epoch * steps_per_epoch + counter //
+    round_words``; it is taken mod ``n`` factor by factor, so nothing
+    overflows 32 bits. Consecutive steps differ by one, or by two where a
+    run resumed mid-epoch at a base that is not a whole number of steps
+    reaches the epoch's end: for ``n >= 3`` (every chain) the initiator
+    moves on every step."""
+    u32 = jnp.uint32
+    per_epoch = (2**32 // round_words) % n
+    k = jnp.asarray(counter, u32) // u32(min(round_words, 2**32 - 1))
+    return ((jnp.asarray(epoch, u32) % u32(n)) * u32(per_epoch)
+            + k % u32(n)) % u32(n)
+
+
 @dataclasses.dataclass
 class TrainStepBundle:
-    """Everything the launcher needs: the jitted step + state builders."""
-    step_fn: Any          # (state, batch, counter, alive) -> (state, metrics)
-    init_state_fn: Any    # params -> state
-    state_shardings: Any  # pytree of NamedSharding (for jit donation / ckpt)
+    """Everything the launcher needs: the jitted step + state builders.
+
+    ``step_fn(state, tokens, reservation, prefix=None, weights=None,
+    alive=None) -> (state, metrics)``; ``reservation`` is the
+    ``(epoch, base)`` that ``aggregator.reserve_round(round_words)``
+    returned for this step, and is consumed by it."""
+    step_fn: Any
+    init_state_fn: Any    # params -> state, placed as the step takes it
+    state_shardings: Any  # NamedShardings of the state's arrays
     batch_spec: Any       # PartitionSpec for the token batch
     sec_size: int
     padded_size: int
+    round_words: int      # counter words one step reserves
     jit_fn: Any = None    # raw jitted shard_map step (dry-run lowering)
     params_abs: Any = None  # abstract params (local-expert view if EP)
     leafwise: bool = False
@@ -107,6 +132,8 @@ def make_train_step(
     sec_size = tree_size(sec_abs)
     shard_len = -(-sec_size // n)
     padded_size = shard_len * n
+    # the chain's payload, plus the weight word of a weighted mean
+    round_words = padded_size + (1 if aggregator.cfg.weighted else 0)
     if leafwise is None:
         leafwise = sec_size * 4 > 8e9
     # per-leaf counter offsets (static): disjoint keystream ranges
@@ -136,7 +163,7 @@ def make_train_step(
     # ---- per-rank step (inside shard_map) -----------------------------------
     def per_rank_step(params, master_shard, fopt_m, fopt_v, fopt_step,
                       ep_opt_state, sec_opt_state, tokens, prefix, weights,
-                      counter, alive):
+                      epoch, counter, alive):
         tokens = tokens.reshape(tokens.shape[1:])  # drop learner dim
         if prefix is not None:
             prefix = prefix.reshape(prefix.shape[1:])
@@ -146,13 +173,15 @@ def make_train_step(
             logits, aux = model.forward(p, tokens, prefix)
             return next_token_loss(logits, tokens, cfg.prefix_embeds) + aux
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        with jax.named_scope(FWD_BWD):
+            loss, grads = jax.value_and_grad(loss_fn)(params)
 
         sec_g, ep_g = partition_tree(grads, lambda p: not is_expert_path(p))
         sec_params_tpl, _ = partition_tree(params,
                                            lambda p: not is_expert_path(p))
-        # §8 collusion mitigation: rotate the initiator role every round
-        rotate = (counter % jnp.uint32(2 * n + 1)).astype(jnp.int32)
+        # §8 collusion mitigation: the initiator role moves on every step
+        rotate = initiator_rotation(epoch, counter, round_words,
+                                    n).astype(jnp.int32)
 
         from repro.optim.adamw import AdamState
         fstate = AdamState(fopt_step, fopt_m, fopt_v)
@@ -165,8 +194,10 @@ def make_train_step(
                 v = leaf.reshape(-1).astype(jnp.float32)
                 if chain_model_sharded:
                     v = jax.lax.with_sharding_constraint(v, P("model"))
-                a = aggregator.aggregate(v, counter, alive=alive,
-                                         domain=idx + 1, rotate=rotate)
+                with jax.named_scope(SAFE_CHAIN):
+                    a = aggregator.aggregate(v, counter, alive=alive,
+                                             domain=idx + 1, rotate=rotate,
+                                             epoch=epoch)
                 avg_leaves.append(a.reshape(leaf.shape))
             avg_tree = jax.tree.unflatten(treedef, avg_leaves)
             new_sec, sec_opt_state = sec_opt.update(avg_tree, sec_opt_state,
@@ -182,19 +213,23 @@ def make_train_step(
                 flat_g = jax.lax.with_sharding_constraint(flat_g, P("model"))
 
             # ---- the paper's technique: secure gradient aggregation ----
-            avg = aggregator.aggregate(flat_g, counter, alive=alive,
-                                       rotate=rotate)
+            with jax.named_scope(SAFE_CHAIN):
+                avg = aggregator.aggregate(flat_g, counter, alive=alive,
+                                           rotate=rotate, epoch=epoch)
 
             # ---- ZeRO-1 slice update (public post-aggregation) ----
-            rank = jax.lax.axis_index(learner_axis)
-            gshard = jax.lax.dynamic_slice(avg, (rank * shard_len,),
-                                           (shard_len,))
-            new_master, fstate = flat_opt.update(gshard, fstate, master_shard)
-            new_flat = jax.lax.all_gather(new_master, learner_axis, tiled=True)
-            if pod_axis is not None:
-                new_flat = jax.lax.pmean(new_flat, pod_axis)  # identical anyway
-            new_sec = flat_to_tree(new_flat[:sec_size], sec_params_tpl)
-            grad_norm = jnp.sqrt(jnp.sum(jnp.square(avg[:sec_size])))
+            with jax.named_scope(ZERO1):
+                rank = jax.lax.axis_index(learner_axis)
+                gshard = jax.lax.dynamic_slice(avg, (rank * shard_len,),
+                                               (shard_len,))
+                new_master, fstate = flat_opt.update(gshard, fstate,
+                                                     master_shard)
+                new_flat = jax.lax.all_gather(new_master, learner_axis,
+                                              tiled=True)
+                if pod_axis is not None:
+                    new_flat = jax.lax.pmean(new_flat, pod_axis)  # identical
+                new_sec = flat_to_tree(new_flat[:sec_size], sec_params_tpl)
+                grad_norm = jnp.sqrt(jnp.sum(jnp.square(avg[:sec_size])))
         # anchor the rebuilt params to the Megatron-TP layout — without
         # this the all-gathered tree comes out replicated per device
         new_sec = jax.tree.map(
@@ -266,6 +301,7 @@ def make_train_step(
         batch_spec,          # tokens [pods*n, B_l, S]
         batch_spec if cfg.prefix_embeds else P(),  # prefix embeds or dummy
         P(),                 # weights [n]
+        P(),                 # key epoch
         P(),                 # counter
         P(),                 # alive [n]
     )
@@ -276,8 +312,8 @@ def make_train_step(
         P(),                 # metrics (replicated)
     )
 
-    def wrapped(params, master, fm, fv, fstep, ep_state, sec_state, tokens,
-                prefix, weights, counter, alive):
+    def train_step(params, master, fm, fv, fstep, ep_state, sec_state, tokens,
+                   prefix, weights, epoch, counter, alive):
         if not cfg.prefix_embeds:
             prefix = None
         if not use_ep:
@@ -285,8 +321,8 @@ def make_train_step(
         if not leafwise:
             sec_state = None
         out = per_rank_step(params, master, fm, fv, fstep, ep_state,
-                            sec_state, tokens, prefix, weights, counter,
-                            alive)
+                            sec_state, tokens, prefix, weights, epoch,
+                            counter, alive)
         out = list(out)
         if not use_ep:
             out[5] = jnp.zeros(())
@@ -295,51 +331,84 @@ def make_train_step(
         return tuple(out)
 
     shard_fn = jax.shard_map(
-        wrapped, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        train_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         axis_names=frozenset(manual), check_vma=False)
 
-    jit_fn = jax.jit(shard_fn,
-                     donate_argnums=(0, 1, 2, 3, 5, 6) if donate else ())
+    # the optimizer states of absent partitions are shared placeholders
+    donated = (0, 1, 2, 3) + ((5,) if use_ep else ()) + \
+        ((6,) if leafwise else ())
+    jit_fn = jax.jit(shard_fn, donate_argnums=donated if donate else ())
 
-    # ---- state init -----------------------------------------------------------
-    def init_state_fn(params):
+    # ---- state init, placed as the step takes it ------------------------------
+    def _sharding(spec):
+        return NamedSharding(mesh, spec)
+
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    state_shardings = {
+        "params": jax.tree.map(_sharding, params_specs, is_leaf=is_spec),
+        "master": _sharding(flat_spec),
+        "fm": _sharding(flat_spec),
+        "fv": _sharding(flat_spec),
+        "fstep": _sharding(P()),
+        "ep_opt": (jax.tree.map(_sharding, ep_opt_specs, is_leaf=is_spec)
+                   if use_ep else None),
+        "sec_opt": (jax.tree.map(_sharding, sec_opt_specs, is_leaf=is_spec)
+                    if leafwise else None),
+    }
+
+    @functools.partial(jax.jit, out_shardings=state_shardings)
+    def _init_arrays(params):
         sec_p, _ = partition_tree(params, lambda p: not is_expert_path(p))
         if leafwise:
             flat = jnp.zeros((n,), jnp.float32)  # 1 elem/rank placeholder
         else:
             flat = tree_to_flat(sec_p)
             flat = jnp.pad(flat, (0, padded_size - sec_size))
-        state = {
+        ep_state = None
+        if use_ep:
+            _, ep_p = partition_tree(params, lambda p: not is_expert_path(p))
+            ep_state = ep_opt.init(ep_p)
+        return {
             "params": params,
             "master": flat,
             "fm": jnp.zeros_like(flat),
             "fv": jnp.zeros_like(flat),
             "fstep": jnp.zeros((), jnp.int32),
-            "ep_opt": None,
+            "ep_opt": ep_state,
             "sec_opt": sec_opt.init(sec_p) if leafwise else None,
-            "step": 0,
         }
-        if use_ep:
-            _, ep_p = partition_tree(params, lambda p: not is_expert_path(p))
-            state["ep_opt"] = ep_opt.init(ep_p)
-        return state
 
-    def step_fn(state, tokens, prefix=None, weights=None, counter=0,
+    def init_state_fn(params):
+        """The step's state, on the devices and in the layout the step
+        takes and returns, so the first step compiles what every later
+        step runs."""
+        return {**_init_arrays(params), "step": 0}
+
+    @functools.cache
+    def constants():
+        """Per-step inputs that never change (all-ones weights and alive
+        bitmap, the absent prefix and optimizer states), placed once on
+        the first step."""
+        put = functools.partial(jax.device_put, device=_sharding(P()))
+        return (put(np.ones((n,), np.float32)),
+                put(np.zeros((1,), np.float32)),
+                put(np.zeros((), np.float32)))
+
+    def step_fn(state, tokens, reservation, prefix=None, weights=None,
                 alive=None):
-        if weights is None:
-            weights = jnp.ones((n,), jnp.float32)
-        if alive is None:
-            alive = jnp.ones((n,), jnp.float32)
-        if prefix is None:
-            prefix = jnp.zeros((1,), jnp.float32)  # dummy
-        ep_state = state["ep_opt"] if use_ep else jnp.zeros(())
-        sec_state = state["sec_opt"] if leafwise else jnp.zeros(())
+        epoch, counter = reservation
+        all_ones, no_prefix, no_state = constants()
+        ep_state = state["ep_opt"] if use_ep else no_state
+        sec_state = state["sec_opt"] if leafwise else no_state
         with jax.set_mesh(mesh):
             (params, master, fm, fv, fstep, ep_state, sec_state,
              metrics) = jit_fn(
                 state["params"], state["master"], state["fm"], state["fv"],
-                state["fstep"], ep_state, sec_state, tokens, prefix, weights,
-                jnp.asarray(counter, jnp.uint32), alive)
+                state["fstep"], ep_state, sec_state, tokens,
+                no_prefix if prefix is None else prefix,
+                all_ones if weights is None else weights,
+                np.uint32(epoch), np.uint32(counter),
+                all_ones if alive is None else alive)
         new_state = {
             "params": params, "master": master, "fm": fm, "fv": fv,
             "fstep": fstep, "ep_opt": ep_state if use_ep else None,
@@ -351,10 +420,11 @@ def make_train_step(
     return TrainStepBundle(
         step_fn=step_fn,
         init_state_fn=init_state_fn,
-        state_shardings=None,
+        state_shardings=state_shardings,
         batch_spec=batch_spec,
         sec_size=sec_size,
         padded_size=padded_size,
+        round_words=round_words,
         jit_fn=jit_fn,
         params_abs=params_abs,
         leafwise=leafwise,

@@ -148,3 +148,30 @@ def test_rwkv6_state_decode_is_constant_memory():
     leaves = jax.tree.leaves(cache)
     total = sum(np.prod(np.shape(l)) for l in leaves)
     assert total < 2**22, "rwkv6 cache must be O(1) in sequence length"
+
+
+def test_norm_gains_stay_float32_when_stacked():
+    """Model.init stores weight matrices in bf16 and keeps every norm gain
+    in f32, the stacked per-layer gains (2-D) included."""
+    cfg = get_smoke_config("internlm2-1.8b")
+    params = Model(cfg).init(jax.random.key(0))
+    blk = params["blocks"][0]
+    assert blk["ln1"]["scale"].ndim == 2
+    for gain in (blk["ln1"]["scale"], blk["ln2"]["scale"],
+                 params["final_norm"]["scale"]):
+        assert gain.dtype == jnp.float32
+    for w in (blk["attn"]["wq"], blk["mlp"]["wo"], params["embed"],
+              params["lm_head"]):
+        assert w.dtype == jnp.bfloat16
+
+
+def test_internlm2_published_update_size():
+    """InternLM2-1.8B at its published widths (untied head, per its
+    config.json) cut to 4 layers aggregates 630,736,896 words a step:
+    4 x 62,918,656 per layer + 2 x 189,530,112 for the embedding and the
+    head + 2,048 for the final norm."""
+    from repro.train.flatten import tree_size
+    cfg = dataclasses.replace(get_config("internlm2-1.8b"), n_layers=4)
+    assert not cfg.tie_embeddings and cfg.norm_eps == 1e-5
+    size = tree_size(jax.eval_shape(Model(cfg).init, jax.random.key(0)))
+    assert size == 630_736_896 == 4 * 62_918_656 + 2 * 189_530_112 + 2_048

@@ -38,7 +38,7 @@ state = bundle.init_state_fn(model.init(jax.random.key(0)))
 losses = []
 for step in range(8):
     state, m = bundle.step_fn(state, batches[step % 2],
-                              counter=step * (bundle.padded_size + 2))
+                              agg.reserve_round(bundle.round_words))
     losses.append(float(m["loss"]))
 assert losses[-1] < losses[0] - 0.5, f"insufficient learning: {losses}"
 

@@ -31,7 +31,7 @@ def run(mode, steps=4):
     s = b.init_state_fn(model.init(jax.random.key(0)))
     ls = []
     for i in range(steps):
-        s, m = b.step_fn(s, jnp.asarray(toks), counter=i * b.padded_size * 4)
+        s, m = b.step_fn(s, jnp.asarray(toks), agg.reserve_round(b.round_words))
         ls.append(float(m["loss"]))
     return ls
 
@@ -63,13 +63,13 @@ toks = np.random.RandomState(0).randint(0, cfg.vocab, (4, 2, 64)).astype(np.int3
 alive = jnp.array([1., 1., 0., 1.])  # learner 2 dead (progress failover)
 losses = []
 for i in range(4):
-    s, m = b.step_fn(s, jnp.asarray(toks), counter=i * b.padded_size * 4,
+    s, m = b.step_fn(s, jnp.asarray(toks), agg.reserve_round(b.round_words),
                      alive=alive)
     losses.append(float(m["loss"]))
 assert losses[-1] < losses[0] and np.isfinite(losses).all()
 # initiator failure: rank 0 dead
 alive0 = jnp.array([0., 1., 1., 1.])
-s, m = b.step_fn(s, jnp.asarray(toks), counter=10 * b.padded_size * 4,
+s, m = b.step_fn(s, jnp.asarray(toks), agg.reserve_round(b.round_words),
                  alive=alive0)
 assert np.isfinite(float(m["loss"]))
 print("FAILOVER_TRAIN_OK")
@@ -96,8 +96,8 @@ toks = np.random.RandomState(0).randint(0, cfg.vocab, (4, 2, 2, 64)).astype(np.i
 w = jnp.array([1000., 2000., 1500., 500.])
 losses = []
 for r in range(3):
-    params, m = b.round_fn(params, jnp.asarray(toks), weights=w,
-                           counter=r * 50_000_000)
+    params, m = b.round_fn(params, jnp.asarray(toks),
+                           agg.reserve_round(b.round_words), weights=w)
     losses.append(float(m["local_loss"]))
 assert losses[-1] < losses[0], losses
 print("FED_OK")
@@ -132,13 +132,16 @@ w = (1000.0 * (1.0 + np.arange(n))).astype(np.float32)  # private org sizes
 wf = make_wire_federated(model, dict((i + 1, toks[i]) for i in range(n)),
                          local_steps=2, local_lr=1e-3)
 W = wf.words_per_round(weighted=True)  # counter stride both planes share
+assert b.round_words == W, (b.round_words, W)
 
 # in-SPMD reference: R rounds, counter advancing W words per round
 p_spmd = model.init(jax.random.key(0))
 spmd_deltas = []
 for r in range(R):
-    p_spmd, m = b.round_fn(p_spmd, jnp.asarray(toks),
-                           weights=jnp.asarray(w), counter=r * W)
+    slot = agg.reserve_round(b.round_words)
+    assert slot == (0, r * W), slot
+    p_spmd, m = b.round_fn(p_spmd, jnp.asarray(toks), slot,
+                           weights=jnp.asarray(w))
     spmd_deltas.append(np.asarray(m["avg_delta"]))
 
 # wire plane: same seeds, real local steps per learner, the SAME R
