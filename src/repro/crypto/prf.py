@@ -99,10 +99,11 @@ def keystream_pair_lanes(key: jax.Array, n: int, counter_base: jax.Array | int =
     This is the schedule the Pallas kernel implements: block ``b`` yields
     words ``(2b, 2b+1)``. Bit-exact oracle for ``kernels.threefry_mask_add``.
 
-    Word ``i`` evaluates its own block and selects lane ``i & 1``, as the
-    kernel does. Interleaving the two lanes instead would need a
-    ``[n/2, 2]`` intermediate, which the TPU tiles to 128 lanes (64x
-    its size); this form keeps every array 1-D.
+    Word ``i`` evaluates its own block and selects lane ``i & 1``; the
+    kernel evaluates each block once, for both its words. Interleaving
+    the two lanes instead would need a ``[n/2, 2]`` intermediate, which
+    the TPU tiles to 128 lanes (64x its size); this form keeps every
+    array 1-D.
     """
     if isinstance(counter_base, (int, np.integer)):
         counter_base = np.uint32(int(counter_base) & 0xFFFFFFFF)

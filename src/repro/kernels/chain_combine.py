@@ -8,8 +8,10 @@ Both pads are generated in-register (VPU) and never materialized; the
 kernel reads ``cipher`` and ``x`` once and writes ``out`` once — 12 bytes
 of HBM traffic per element instead of 28+ for the unfused sequence
 (pad_in read+write, decrypt read+write, encode, pad_out read+write, add).
-On a TPU v5e it moves those 12 B at about a quarter of HBM bandwidth: the
-two pads' Threefry rounds on the VPU set its bound (PERF.md §5).
+On a TPU v5e it moves those 12 B at about a quarter of HBM bandwidth. At
+blocks of 64 rows each grid step's fixed cost sets the bound, not the
+VPU's throughput: halving the Threefry work cut the hop by a tenth, a
+block of 1,024 rows by three fifths (PERF.md §5).
 
 The wrapper runs the kernel over a (rows, 128) view of its operands
 (``lane_view``) and lets the output take the incoming cipher's buffer

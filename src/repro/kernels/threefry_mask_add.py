@@ -7,10 +7,10 @@ add); this kernel does one read of ``x`` and one write of the masked
 ciphertext — the pad never touches HBM.
 
 TPU adaptation notes (DESIGN.md §4):
-  * masking is element-wise VPU work, and the Threefry rounds set the
-    bound, not HBM: on a TPU v5e the hop kernel moves its 12 B/word at
-    about a quarter of HBM bandwidth (PERF.md §5). Fusion still saves the
-    pad's HBM round trips;
+  * masking is element-wise VPU work, and HBM does not set the bound: on
+    a TPU v5e the hop kernel moves its 12 B/word at about a quarter of
+    HBM bandwidth, bound at 64-row blocks by each grid step's fixed cost
+    (PERF.md §5). Fusion still saves the pad's HBM round trips;
   * blocks are (block_rows, 128): lane-dim 128 matches the VPU/VREG lane
     width, block_rows a multiple of 8 for f32 sublane packing;
   * the wrappers run the grid over a (rows, 128) view of the update
@@ -18,9 +18,12 @@ TPU adaptation notes (DESIGN.md §4):
     update of a multiple of 128 words is viewed in place, with no copy
     (a flat 32-bit array and its (rows, 128) view share one tiled
     layout on the TPU);
-  * each element evaluates the full Threefry-2x32 block for its counter
-    and selects its lane — lane-redundant (2× VPU flops) but gather-free
-    and layout-preserving.
+  * one Threefry-2x32 block gives two words, and each block is
+    evaluated once: a tile's top half takes the blocks on the even lanes
+    of a (block_rows/2, 128) counter tile, its bottom half those on the
+    odd lanes, and a lane roll (XLU, not VPU) moves each block's other
+    word beside its pair (``pad_for_block``). The word->counter map is
+    ``keystream_pair_lanes``'s, so the pads are bit-identical to it.
 """
 from __future__ import annotations
 
@@ -82,22 +85,38 @@ def threefry2x32_block(k0, k1, x0, x1):
             x1 = _rotl32(x1, r)
             x1 = x1 ^ x0
         x0 = x0 + ks[(i + 1) % 3]
-        x1 = x1 + ks[(i + 2) % 3] + jnp.uint32(i + 1)
+        # the round constant joins the key word first, on scalars: one
+        # vector add, not two
+        x1 = x1 + (ks[(i + 2) % 3] + jnp.uint32(i + 1))
     return x0, x1
 
 
 def pad_for_block(k0, k1, base, block_shape, row_offset):
     """uint32 keystream for a (rows, LANE) tile starting at flat offset
     ``row_offset*LANE``, matching crypto.prf.keystream_pair_lanes:
-    word i = lane (i & 1) of Threefry(key, base + i//2)."""
+    word i = lane (i & 1) of Threefry(key, base + i//2).
+
+    A tile's rows*LANE/2 blocks fill one (rows/2, LANE) tile of counters:
+    even lanes hold the top half's blocks, odd lanes the bottom half's,
+    each evaluated once. A top pair (2j, 2j+1) takes lane 0 of the block
+    at lane 2j and lane 1 of the same block, rolled one lane right; a
+    bottom pair takes lane 0 of the block at lane 2j+1, rolled one lane
+    left, and lane 1 of it. The rolls run on the XLU, not the VPU."""
     rows, lanes = block_shape
-    row = jax.lax.broadcasted_iota(jnp.uint32, block_shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, block_shape, 1)
-    linear = (row + row_offset) * jnp.uint32(lanes) + col
-    ctr = base + (linear >> 1)
-    lane_sel = (linear & jnp.uint32(1)).astype(jnp.bool_)
+    assert rows % 2 == 0, (
+        f"pad_for_block pairs a tile's top and bottom halves: rows must "
+        f"be even, got {rows}")
+    half = (rows // 2, lanes)
+    col = jax.lax.broadcasted_iota(jnp.uint32, half, 1)
+    odd = col & jnp.uint32(1)
+    row = (jax.lax.broadcasted_iota(jnp.uint32, half, 0) + row_offset
+           + odd * jnp.uint32(rows // 2))
+    ctr = base + ((row * jnp.uint32(lanes) + col) >> 1)
     y0, y1 = threefry2x32_block(k0, k1, ctr, jnp.zeros_like(ctr))
-    return jnp.where(lane_sel, y1, y0)
+    odd = odd.astype(jnp.bool_)
+    top = jnp.where(odd, pltpu.roll(y1, 1, 1), y0)
+    bottom = jnp.where(odd, y1, pltpu.roll(y0, lanes - 1, 1))
+    return jnp.concatenate([top, bottom], axis=0)
 
 
 def encode_block(x, scale_bits: int):

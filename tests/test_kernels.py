@@ -9,11 +9,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+import repro.kernels.threefry_mask_add as tma
+from repro.crypto.prf import keystream_pair_lanes
 from repro.kernels.ops import (bon_mask, chain_combine,
                                chain_combine_batched, mask_add)
 from repro.kernels.ref import (bon_mask_ref, chain_combine_batched_ref,
                                chain_combine_ref, mask_add_ref)
+from repro.kernels.threefry_mask_add import LANE, pad_for_block
 from repro.kernels.threefry_mask_add import mask_add as raw_mask_add
 
 #: the kinds of V the wrappers lay out differently, each viewed in place
@@ -56,6 +61,59 @@ def test_mask_add_block_shapes(block_rows):
     got = raw_mask_add(x, key, 0, block_rows=block_rows, interpret=True)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(mask_add_ref(x, key, 0)))
+
+
+def _pads(key, base, V, block_rows):
+    """``pad_for_block`` alone over a grid of (block_rows, 128) tiles that
+    cover V words, through a one-output kernel: compiled on a TPU,
+    interpreted on the CPU. The kernel is built anew on every call."""
+    rows = pl.cdiv(V, LANE)
+
+    def kernel(s, o_ref):
+        o_ref[...] = pad_for_block(s[0], s[1], s[2], o_ref.shape,
+                                   jnp.uint32(pl.program_id(0) * block_rows))
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(pl.cdiv(rows, block_rows),),
+            in_specs=[],
+            out_specs=pl.BlockSpec((block_rows, LANE), lambda i, s: (i, 0))),
+        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.uint32),
+        interpret=jax.default_backend() == "cpu",
+    )(jnp.concatenate([key, jnp.asarray([base], jnp.uint32)]))
+    return out.reshape(-1)[:V]
+
+
+@pytest.mark.parametrize("block_rows", [16, 64])
+@pytest.mark.parametrize("base", [0, 2**32 - 3])
+def test_pad_for_block_is_keystream_pair_lanes(block_rows, base):
+    """Word for word the jnp keystream, over several blocks that end in a
+    ragged one and a part row; from base 2^32 - 3 the counter wraps
+    inside the first tile."""
+    V = (3 * 64 + 24) * LANE + 5
+    key = jnp.array([0x9E3779B9, 0x7F4A7C15], jnp.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(_pads(key, base, V, block_rows)),
+        np.asarray(keystream_pair_lanes(key, V, base)))
+
+
+def test_pad_for_block_evaluates_each_block_once(monkeypatch):
+    """A (64, 128) tile's 4,096 words are 2,048 Threefry blocks: one pad
+    evaluates them as one (32, 128) tile, never a block per word."""
+    shapes = []
+    block = tma.threefry2x32_block
+
+    def recording(k0, k1, x0, x1):
+        shapes.append(x0.shape)
+        return block(k0, k1, x0, x1)
+
+    monkeypatch.setattr(tma, "threefry2x32_block", recording)
+    key = jnp.array([5, 6], jnp.uint32)
+    got = _pads(key, 7, 64 * LANE, 64)
+    assert shapes == [(32, LANE)]
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(keystream_pair_lanes(key, 64 * LANE, 7)))
 
 
 @pytest.mark.parametrize("scale_bits", [8, 16, 24])
