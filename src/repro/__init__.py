@@ -11,7 +11,7 @@ Public API surface:
   repro.configs   — get_config / get_smoke_config / all_arch_ids
   repro.train     — make_train_step, make_federated_round
   repro.serve     — ServeEngine, AggregationEngine
-  repro.launch    — production meshes, multi-pod dry-run, CLIs
+  repro.launch    — the mesh constructor, compile cache, train/serve CLIs
 
 See ARCHITECTURE.md for the two-plane + topology-layer picture.
 """

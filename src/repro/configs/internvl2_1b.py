@@ -1,8 +1,9 @@
 """InternVL2-1B — InternViT frontend + Qwen2-0.5B-class LLM backbone.
 
 [vlm] 24L d_model=896 14H (GQA kv=2) d_ff=4864 vocab=151655
-[arXiv:2404.16821]. The ViT+projector is the stub frontend: input_specs
-provides 256 precomputed patch embeddings prefixed to the text tokens.
+[arXiv:2404.16821]. The ViT+projector is the stub frontend: the
+caller provides 256 precomputed patch embeddings (``prefix``) prefixed to
+the text tokens.
 Pure global attention -> long_500k skipped.
 """
 from repro.models.config import ModelConfig

@@ -3,7 +3,7 @@
 [audio] 48L d_model=2048 32H (GQA kv=32) d_ff=8192 vocab=2048
 [arXiv:2306.05284]. 4 parallel codebooks (delay pattern): embeddings are
 summed, one lm head per codebook. The EnCodec conv codec itself is the
-modality-frontend stub (input_specs provides frame token ids).
+modality-frontend stub: the model takes frame token ids.
 Pure global attention -> long_500k skipped.
 """
 from repro.models.config import ModelConfig

@@ -1,7 +1,7 @@
 """Aggregator interface — SAFE and baselines as pluggable components.
 
-``SecureAggregator`` is the first-class framework object: the federated
-trainer, the benchmarks, and the dry-run all consume it. The per-rank
+``SecureAggregator`` is the first-class framework object: the train
+step, the federated trainer and the benchmarks all consume it. The per-rank
 ``aggregate`` method composes inside any shard_map region that is manual
 over the learner axis; ``aggregate_sharded`` is a standalone jit entry
 point for tests/benchmarks.
@@ -240,7 +240,6 @@ def make_aggregator(
     weighted: bool = False,
     pod_axis: Optional[str] = None,
     scale_bits: int = 16,
-    unroll: bool = True,
     provisioning_seed: int = 0xC0FFEE,
     learner_master: int = 0x5EED,
 ) -> SecureAggregator:
@@ -254,6 +253,5 @@ def make_aggregator(
         subgroups=subgroups,
         weighted=weighted,
         pod_axis=pod_axis,
-        unroll=unroll,
     )
     return SecureAggregator(cfg, provisioning_seed, learner_master)

@@ -156,11 +156,8 @@ def chain_aggregate_sequential(
         delta = ev - pad_in + pad_out  # decrypt, add local, re-encrypt
         return x + jnp.where(active, delta, jnp.zeros_like(ev))
 
-    if cfg.unroll:
-        for t in range(1, m):
-            x = hop(t, x)
-    else:
-        x = jax.lax.fori_loop(1, m, hop, x)
+    for t in range(1, m):  # unrolled: XLA sees, and overlaps, every hop
+        x = hop(t, x)
 
     # Final hop back to the initiator, which unmasks.
     x = _ring_hop(x, axis, perm)
@@ -250,11 +247,8 @@ def chain_aggregate_pipelined(
         s = (lrank - t) % m  # segment id now resident on this rank
         return c - pads_in[s] + ev[s] + pads_out[s]
 
-    if cfg.unroll:
-        for t in range(1, m):
-            c = step(t, c)
-    else:
-        c = jax.lax.fori_loop(1, m, step, c)
+    for t in range(1, m):
+        c = step(t, c)
 
     # One final hop returns segment lrank to its initiator, which unmasks.
     c = _ring_hop(c, axis, perm)

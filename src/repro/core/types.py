@@ -31,9 +31,6 @@ class ChainConfig:
                  published value is the weighted mean (paper §5.6).
       pod_axis: optional mesh axis for hierarchical federation (§5.10):
                  intra-pod chains then cross-pod average of group averages.
-      unroll: unroll the hop loop in HLO (preferred for n <= 64: exposes
-                 the collective schedule to the roofline parser and lets
-                 XLA overlap; fori_loop otherwise).
     """
 
     axis: str = "data"
@@ -44,7 +41,6 @@ class ChainConfig:
     subgroups: int = 1
     weighted: bool = False
     pod_axis: Optional[str] = None
-    unroll: bool = True
 
     def __post_init__(self) -> None:
         if self.mode not in ("safe", "saf", "insec", "bon"):

@@ -10,7 +10,7 @@ These are the oracles for the Pallas kernels in ``repro.kernels``.
 """
 from repro.crypto.prf import (
     threefry2x32,
-    keystream,
+    keystream_pair_lanes,
     derive_pair_key,
     derive_key,
 )
@@ -21,7 +21,7 @@ from repro.crypto.fixedpoint import (
 
 __all__ = [
     "threefry2x32",
-    "keystream",
+    "keystream_pair_lanes",
     "derive_pair_key",
     "derive_key",
     "FixedPointCodec",

@@ -42,17 +42,6 @@ def threefry2x32_np(key: np.ndarray, x0: np.ndarray, x1: np.ndarray):
         np.seterr(**old)
 
 
-def keystream_np(key: np.ndarray, n: int, counter_base: int = 0) -> np.ndarray:
-    """uint32[n] keystream, single-lane schedule (mirror of prf.keystream)."""
-    old = np.seterr(over="ignore")
-    try:
-        idx = np.arange(n, dtype=np.uint32) + np.uint32(counter_base)
-        y0, _ = threefry2x32_np(key, idx, np.zeros_like(idx))
-        return y0
-    finally:
-        np.seterr(**old)
-
-
 def keystream_pair_lanes_np(key: np.ndarray, n: int, counter_base: int = 0) -> np.ndarray:
     """uint32[n] keystream, two-lane schedule (mirror of
     prf.keystream_pair_lanes and of the Pallas kernel)."""

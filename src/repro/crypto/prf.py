@@ -16,8 +16,6 @@ seed. All arithmetic is uint32 so the masking is exact.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,40 +62,14 @@ def threefry2x32(key: jax.Array, x0: jax.Array, x1: jax.Array):
     return x0, x1
 
 
-@partial(jax.jit, static_argnums=(1,))
-def keystream(key: jax.Array, n: int, counter_base: jax.Array | int = 0) -> jax.Array:
-    """Generate ``n`` uint32 keystream words.
-
-    Word ``i`` is derived from counter ``counter_base + i`` so streams for
-    successive aggregation rounds never overlap when the caller advances
-    ``counter_base`` by at least ``n`` (see ``RoundCounter``).
-
-    Args:
-      key: uint32[2] PRF key.
-      n: number of words (static).
-      counter_base: uint32 starting counter (traced ok).
-
-    Returns:
-      uint32[n] keystream.
-    """
-    if isinstance(counter_base, (int, np.integer)):
-        counter_base = np.uint32(int(counter_base) & 0xFFFFFFFF)
-    with jax.named_scope(KEYSTREAM):
-        base = jnp.asarray(counter_base, jnp.uint32)
-        idx = jnp.arange(n, dtype=jnp.uint32)
-        # Counter words: (block index, lane). Two output words per block
-        # would halve PRF work; we deliberately keep 1 word/counter here for
-        # clarity — the fused Pallas kernel uses both lanes (see
-        # kernels/threefry_mask_add).
-        y0, _ = threefry2x32(key, base + idx, jnp.zeros_like(idx))
-        return y0
-
-
 def keystream_pair_lanes(key: jax.Array, n: int, counter_base: jax.Array | int = 0) -> jax.Array:
-    """Keystream using both Threefry output lanes (half the PRF invocations).
+    """Generate ``n`` uint32 keystream words from both Threefry output lanes.
 
-    This is the schedule the Pallas kernel implements: block ``b`` yields
-    words ``(2b, 2b+1)``. Bit-exact oracle for ``kernels.threefry_mask_add``.
+    Block ``counter_base + b`` yields words ``(2b, 2b+1)``, so streams of
+    successive rounds never overlap when the caller advances
+    ``counter_base`` by at least ``ceil(n / 2)`` blocks (see
+    ``RoundCounter``). This is the schedule the Pallas kernel implements;
+    bit-exact oracle for ``kernels.threefry_mask_add``.
 
     Word ``i`` evaluates its own block and selects lane ``i & 1``; the
     kernel evaluates each block once, for both its words. Interleaving

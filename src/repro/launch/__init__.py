@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve CLIs."""
+"""Launchers: the mesh constructor, the compile cache, train/serve CLIs."""

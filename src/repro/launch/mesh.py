@@ -4,16 +4,12 @@
 ``AxisType.Auto``. ``jax.make_mesh`` alone makes Explicit axes, on which
 ``with_sharding_constraint`` refuses the train step's Megatron specs.
 
-Single pod: 16×16 = 256 chips, axes (data, model) — 'data' is the
-learner/chain axis (one SAFE learner per data rank), 'model' the
-tensor-parallel axis.
+Axes (data, model): 'data' is the learner/chain axis (one SAFE learner
+per data rank), 'model' the tensor-parallel axis. A leading 'pod' axis
+is the hierarchical-federation axis (paper §5.10): intra-pod SAFE chains,
+then a plain mean of the already-anonymized pod averages across pods.
 
-Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model) — 'pod' is the
-hierarchical-federation axis (paper §5.10): intra-pod SAFE chains, then a
-plain mean of the already-anonymized pod averages across pods.
-
-Defined as functions so importing this module never touches device state
-(dryrun.py must set XLA_FLAGS before the first jax call).
+A function, so importing this module never touches device state.
 """
 from __future__ import annotations
 
@@ -38,8 +34,3 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=list(devices)[:n])
 
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)

@@ -81,11 +81,6 @@ class ModelConfig:
     # supports the long_500k shape (sub-quadratic path exists)
     subquadratic: bool = False
     dtype: str = "bfloat16"
-    # manual expert parallelism (set by the train-step builder for giant
-    # MoEs): experts sharded over this manual mesh axis, dispatch via
-    # explicit all_to_all. None -> GSPMD-auto expert sharding.
-    ep_axis: Optional[str] = None
-    ep_ranks: int = 1
 
     def __post_init__(self):
         if self.n_layers % len(self.pattern) != 0:

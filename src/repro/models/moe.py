@@ -9,14 +9,12 @@ Production layout (MaxText/Switch-style, flop-honest):
      i.e. top_k/E of the dense-all-experts cost,
   4. weighted scatter-add back.
 
-Expert weights are sharded over the 'model' axis (expert parallel):
-GSPMD turns the dispatch gather/scatter into the all-to-all that
-dominates MoE roofline collectives. The auxiliary load-balance loss is
-returned to the caller (summed into the train loss).
+Expert weights carry the layout of ``models/sharding.py`` (experts over
+'data', expert-ff over 'model'); GSPMD derives the dispatch from it. The
+auxiliary load-balance loss is returned to the caller (summed into the
+train loss).
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,18 +40,9 @@ def moe_init(rng, d: int, moe_cfg) -> dict:
     return params
 
 
-def moe_apply(params: dict, x: jax.Array, moe_cfg, ep_axis=None,
-              ep_ranks: int = 1) -> tuple[jax.Array, jax.Array]:
-    """x: [B, S, d] -> (y, aux_loss).
-
-    ep_axis: when set (giant-MoE train path), expert weights arrive as the
-    *local shard* [E/ep_ranks, ...] of a manual mesh axis and dispatch
-    goes through an explicit all_to_all over that axis (DeepSpeed-MoE-style
-    expert parallelism over the learner axis; DESIGN.md §3 caveat: expert
-    gradients are combined by the a2a transpose, outside the SAFE boundary).
-    """
-    if ep_axis is not None:
-        return _moe_apply_ep(params, x, moe_cfg, ep_axis, ep_ranks)
+def moe_apply(params: dict, x: jax.Array,
+              moe_cfg) -> tuple[jax.Array, jax.Array]:
+    """x: [B, S, d] -> (y, aux_loss)."""
     B, S, d = x.shape
     E, k = moe_cfg.num_experts, moe_cfg.top_k
     capacity_factor = moe_cfg.capacity_factor
@@ -104,79 +93,6 @@ def moe_apply(params: dict, x: jax.Array, moe_cfg, ep_axis=None,
     gate_of_slot = jnp.zeros((E * C + 1,), x.dtype).at[slot].set(
         gates_sorted.astype(x.dtype))[: E * C]
     contrib = ye.reshape(E * C, d) * gate_of_slot[:, None]
-    y = jnp.zeros((T + 1, d), x.dtype).at[dispatch_tok].add(contrib)[:T]
-
-    if moe_cfg.num_shared_experts:
-        hs = (xt @ params["shared_wi"].astype(x.dtype)) * jax.nn.silu(
-            xt @ params["shared_wg"].astype(x.dtype))
-        y = y + hs @ params["shared_wo"].astype(x.dtype)
-
-    return y.reshape(B, S, d), aux
-
-
-def _dispatch_indices(probs, k: int, E: int, T: int, capacity_factor: float):
-    """Shared routing plumbing: returns (dispatch_tok[E*C], gate_of_slot,
-    C, aux_frac) — see moe_apply for the algorithm."""
-    gate_vals, assign = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    counts = jnp.zeros((E,), jnp.float32).at[assign.reshape(-1)].add(1.0)
-    frac = counts / (T * k)
-    C = int(np.ceil(T * k / E * capacity_factor))
-    C = max(4, min(C, T))
-    flat_assign = assign.reshape(-1)
-    order = jnp.argsort(flat_assign, stable=True)
-    sorted_e = flat_assign[order]
-    seg_start = jnp.searchsorted(sorted_e, jnp.arange(E))
-    pos_in_e = jnp.arange(T * k) - seg_start[sorted_e]
-    keep = pos_in_e < C
-    slot = jnp.where(keep, sorted_e * C + pos_in_e, E * C)
-    token_of = order // k
-    dispatch_tok = jnp.full((E * C + 1,), T, jnp.int32).at[slot].set(
-        token_of.astype(jnp.int32))[: E * C]
-    gates_sorted = gate_vals.reshape(-1)[order]
-    gate_of_slot = jnp.zeros((E * C + 1,), jnp.float32).at[slot].set(
-        gates_sorted)[: E * C]
-    return dispatch_tok, gate_of_slot, C, frac
-
-
-def _moe_apply_ep(params: dict, x: jax.Array, moe_cfg, axis: str,
-                  n_ranks: int) -> tuple[jax.Array, jax.Array]:
-    """Manual expert parallelism over a shard_map-manual mesh axis.
-
-    params['wi'/'wg'/'wo'] are the LOCAL expert shards [E/n, d, ff];
-    the router (and shared experts) are replicated. Token buffers do a
-    round trip: dispatch [n, E_loc, C, d] --a2a--> compute --a2a--> combine.
-    """
-    B, S, d = x.shape
-    E, k = moe_cfg.num_experts, moe_cfg.top_k
-    E_loc = E // n_ranks
-    T = B * S
-    xt = x.reshape(T, d)
-
-    logits = (xt @ params["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    dispatch_tok, gate_of_slot, C, frac = _dispatch_indices(
-        probs, k, E, T, moe_cfg.capacity_factor)
-    aux = E * jnp.sum(frac * probs.mean(0)) * moe_cfg.aux_loss_weight
-
-    xpad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], 0)
-    xe = xpad[dispatch_tok].reshape(n_ranks, E_loc, C, d)
-    # exchange: rank r receives, from every source rank s, the tokens
-    # destined for r's local experts — [n_ranks(source), E_loc, C, d]
-    xe = jax.lax.all_to_all(xe, axis, split_axis=0, concat_axis=0, tiled=True)
-    xe = xe.reshape(n_ranks, E_loc, C, d).transpose(1, 0, 2, 3) \
-        .reshape(E_loc, n_ranks * C, d)
-
-    h = jnp.einsum("ecd,edf->ecf", xe, params["wi"].astype(x.dtype))
-    h = h * jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe,
-                                   params["wg"].astype(x.dtype)))
-    ye = jnp.einsum("ecf,efd->ecd", h, params["wo"].astype(x.dtype))
-
-    ye = ye.reshape(E_loc, n_ranks, C, d).transpose(1, 0, 2, 3)
-    ye = jax.lax.all_to_all(ye, axis, split_axis=0, concat_axis=0, tiled=True)
-    ye = ye.reshape(E, C, d)  # back to sender, global-expert major
-
-    contrib = ye.reshape(E * C, d) * gate_of_slot[:, None].astype(x.dtype)
     y = jnp.zeros((T + 1, d), x.dtype).at[dispatch_tok].add(contrib)[:T]
 
     if moe_cfg.num_shared_experts:

@@ -1,4 +1,4 @@
-"""Parameter PartitionSpecs (Megatron TP + expert-parallel layout).
+"""Parameter PartitionSpecs (Megatron TP + the MoE expert layout).
 
 Rules are path-based over the tree built by ``Model.init``:
 
@@ -12,9 +12,10 @@ Rules are path-based over the tree built by ``Model.init``:
   mamba in_proj/out_proj, rwkv projections: like mlp
   norms / scalars       : replicated
 
-MoE experts ride the 'data' axis (expert parallelism — DESIGN.md §3):
-that matches the manual-EP train path (shard_map in_specs take the same
-slice) and gives GSPMD the all-to-all layout when serving.
+MoE experts ride the 'data' axis, which gives GSPMD the all-to-all
+layout when serving. The train step is manual over 'data' and strips it
+from every spec: each learner holds all experts, and their gradients go
+through the SAFE chain with every other weight.
 
 ``Model.init`` params are replicated over 'data' otherwise: the paper's
 cross-org semantics (every learner holds the model) — the ZeRO-1 master
@@ -28,7 +29,10 @@ from jax.sharding import PartitionSpec as P
 import jax
 
 from repro.models.config import ModelConfig
-from repro.train.flatten import _path_str
+
+def _path_str(path) -> str:
+    return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+
 
 _COL = {"wq", "wk", "wv", "wi", "wg", "w_proj", "in_proj",
         "wr", "shared_wi", "shared_wg"}

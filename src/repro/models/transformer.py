@@ -3,7 +3,7 @@
 Depth is ``cfg.pattern`` repeated ``cfg.n_units`` times. Parameters (and
 decode caches) are stacked per pattern position and the forward pass is a
 single ``lax.scan`` over units — compile time and HLO size are
-O(len(pattern)), which is what makes the 94-layer MoE dry-runs tractable.
+O(len(pattern)), not O(depth).
 
 ``shared_attn`` positions (zamba2) use one *unstacked* parameter set
 reused at every occurrence (weight sharing), closed over by the scan
@@ -79,8 +79,7 @@ def block_apply(params: Params, x: jax.Array, cfg: ModelConfig, kind: str,
     aux = jnp.zeros((), jnp.float32)
     if "moe" in params:
         h = rmsnorm(params["ln2"], x, cfg.norm_eps)
-        ff, aux = moe_apply(params["moe"], h, cfg.moe,
-                            ep_axis=cfg.ep_axis, ep_ranks=cfg.ep_ranks)
+        ff, aux = moe_apply(params["moe"], h, cfg.moe)
     elif "mlp" in params:
         h = rmsnorm(params["ln2"], x, cfg.norm_eps)
         ff = mlp_apply(params["mlp"], h)

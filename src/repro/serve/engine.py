@@ -1,8 +1,7 @@
 """Batched serving engine: continuous prefill + decode.
 
-``make_serve_step`` builds the jitted one-token decode step the dry-run
-lowers for the decode_32k / long_500k shapes: ONE new token against a KV
-cache (or recurrent state) of ``seq_len``.
+``make_serve_step`` builds the jitted one-token decode step: ONE new
+token against a KV cache (or recurrent state) of ``seq_len``.
 
 ``ServeEngine`` is the host-side loop the serving example drives: a
 fixed-size batch of slots, each slot holding one request's cache; new

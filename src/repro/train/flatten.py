@@ -1,4 +1,4 @@
-"""Deterministic tree <-> flat-vector codec and path-based partitioning.
+"""Deterministic tree <-> flat-vector codec.
 
 The SAFE chain aggregates a single flat f32 vector (the paper's "feature
 vector" is our gradient); these helpers define the canonical layout.
@@ -8,7 +8,7 @@ the ZeRO-1 shards before any real array exists.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -37,28 +37,3 @@ def flat_to_tree(flat: jax.Array, template: Any) -> Any:
         off += n
     return jax.tree.unflatten(treedef, out)
 
-
-def _path_str(path) -> str:
-    return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-
-
-def partition_tree(tree: Any, pred: Callable[[str], bool]):
-    """Split into (selected, rest) trees; non-matching leaves become None
-    (empty pytree nodes, invisible to tree.map/leaves)."""
-    sel = jax.tree_util.tree_map_with_path(
-        lambda p, x: x if pred(_path_str(p)) else None, tree)
-    rest = jax.tree_util.tree_map_with_path(
-        lambda p, x: None if pred(_path_str(p)) else x, tree)
-    return sel, rest
-
-
-def combine_trees(a: Any, b: Any) -> Any:
-    """Merge two complementary partitions back into one tree."""
-    isn = lambda x: x is None
-    return jax.tree.map(lambda x, y: y if x is None else x, a, b, is_leaf=isn)
-
-
-def is_expert_path(path: str) -> bool:
-    """Expert-parallel leaves: the per-expert matrices inside moe blocks
-    (router and shared experts stay in the secure-aggregated partition)."""
-    return "moe/" in path and path.rsplit("/", 1)[-1] in ("wi", "wg", "wo")

@@ -12,7 +12,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.crypto.prf import keystream, keystream_pair_lanes
+from repro.crypto.prf import keystream_pair_lanes
 from repro.kernels.bon_mask import bon_mask
 from repro.kernels.chain_combine import chain_combine, chain_combine_batched
 from repro.kernels.ref import (bon_mask_ref, chain_combine_batched_ref,
@@ -106,10 +106,14 @@ def test_named_kernels_match_the_reference_exactly(kernel):
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("stream", [keystream, keystream_pair_lanes])
-def test_keystream_is_named(stream):
+@pytest.mark.parametrize("base", ["traced", "static"])
+def test_keystream_is_named(base):
     key = np.array([3, 5], np.uint32)
-    names = op_names(lambda k, b: stream(k, V, b), key, np.uint32(9))
+    if base == "traced":
+        names = op_names(lambda k, b: keystream_pair_lanes(k, V, b), key,
+                         np.uint32(9))
+    else:
+        names = op_names(lambda k: keystream_pair_lanes(k, V, 9), key)
     threefry = [n for n in names if n.endswith(("/xor", "/shift_left"))]
     assert threefry and all(f"/{KEYSTREAM}/" in n for n in threefry)
 

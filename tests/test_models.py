@@ -7,9 +7,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_arch_ids, get_config, get_smoke_config
 from repro.models import Model
+from repro.models.sharding import sanitize_spec
 
 ARCHS = all_arch_ids()
 
@@ -175,3 +177,25 @@ def test_internlm2_published_update_size():
     assert not cfg.tie_embeddings and cfg.norm_eps == 1e-5
     size = tree_size(jax.eval_shape(Model(cfg).init, jax.random.key(0)))
     assert size == 630_736_896 == 4 * 62_918_656 + 2 * 189_530_112 + 2_048
+
+
+class TestSanitizeSpec:
+    def test_drops_non_divisible(self):
+        spec = sanitize_spec(P("model", None), (151655, 896), {"model": 16})
+        assert spec == P(None, None)
+
+    def test_keeps_divisible(self):
+        spec = sanitize_spec(P("model", None), (256, 8), {"model": 16})
+        assert spec == P("model", None)
+
+    def test_tuple_axes(self):
+        spec = sanitize_spec(P(("pod", "data"), None), (64, 8),
+                             {"pod": 2, "data": 16})
+        assert spec == P(("pod", "data"), None)
+        spec = sanitize_spec(P(("pod", "data"), None), (33, 8),
+                             {"pod": 2, "data": 16})
+        assert spec == P(None, None)
+
+    def test_pads_short_spec(self):
+        spec = sanitize_spec(P("model"), (32, 4, 4), {"model": 16})
+        assert spec == P("model", None, None)

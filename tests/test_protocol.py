@@ -233,3 +233,24 @@ class TestDeepEdge:
         # pre-negotiation removes one RSA unwrap (~0.35 s on the Archer C7)
         # per hop — 6 hops here
         assert prenegotiated.virtual_time < hybrid.virtual_time - 6 * 0.3
+
+
+class TestHierarchicalController:
+    def test_parent_averages_children(self):
+        from repro.core.controller import Controller, HierarchicalController
+        import numpy as np
+        kids = []
+        for base in (0.0, 2.0):
+            c = Controller({0: [1, 2, 3]})
+            c.post_average(1, np.full(4, base + 1.0), group=0)
+            kids.append(c)
+        parent = HierarchicalController(kids)
+        res = parent.collect()
+        np.testing.assert_allclose(res["average"], np.full(4, 2.0))
+        assert parent.up_messages == 2
+
+    def test_incomplete_child_rejected(self):
+        from repro.core.controller import Controller, HierarchicalController
+        parent = HierarchicalController([Controller({0: [1, 2, 3]})])
+        with pytest.raises(AssertionError):
+            parent.collect()
