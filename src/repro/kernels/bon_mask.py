@@ -25,8 +25,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.threefry_mask_add import (
     LANE,
     as_u32_scalar,
-    DEFAULT_BLOCK_ROWS,
     encode_block,
+    for_each_subtile,
     lane_view,
     pad_for_block,
 )
@@ -35,15 +35,20 @@ from repro.kernels.threefry_mask_add import (
 def _bon_mask_kernel(scalars, x_ref, o_ref, *, scale_bits: int,
                      block_rows: int, num_keys: int):
     i = pl.program_id(0)
-    off = jnp.uint32(i * block_rows)
-    acc = encode_block(x_ref[...], scale_bits)
     base = scalars[3 * num_keys]
-    for j in range(num_keys):  # static unroll: n is a trace-time constant
-        pad = pad_for_block(scalars[3 * j], scalars[3 * j + 1], base,
-                            x_ref.shape, off)
-        sign_pos = scalars[3 * j + 2] > 0
-        acc = jnp.where(sign_pos, acc + pad, acc - pad)
-    o_ref[...] = acc
+
+    def tile(r, n):
+        off = jnp.uint32(i * block_rows + r)
+        rows = pl.ds(r, n)
+        acc = encode_block(x_ref[rows, :], scale_bits)
+        for j in range(num_keys):  # static unroll: n is a trace-time constant
+            pad = pad_for_block(scalars[3 * j], scalars[3 * j + 1], base,
+                                (n, LANE), off)
+            sign_pos = scalars[3 * j + 2] > 0
+            acc = jnp.where(sign_pos, acc + pad, acc - pad)
+        o_ref[rows, :] = acc
+
+    for_each_subtile(block_rows, tile)
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "block_rows", "interpret"))
@@ -54,13 +59,14 @@ def bon_mask(
     counter_base: jax.Array | int = 0,
     *,
     scale_bits: int = 16,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """x: f32[V]; keys: uint32[m, 2]; signs: int32[m] (+1/−1) -> uint32[V]."""
+    """x: f32[V]; keys: uint32[m, 2]; signs: int32[m] (+1/−1) -> uint32[V].
+    ``block_rows`` None takes the grid's block from the rows."""
     V = x.shape[0]
     m = keys.shape[0]
-    x2, nblocks = lane_view(x, block_rows)
+    x2, block_rows, nblocks = lane_view(x, block_rows)
 
     # scalar layout: [k0_j, k1_j, sign_j]*m + [base]; sign encoded 1/0
     packed = jnp.concatenate([

@@ -8,10 +8,10 @@ Both pads are generated in-register (VPU) and never materialized; the
 kernel reads ``cipher`` and ``x`` once and writes ``out`` once — 12 bytes
 of HBM traffic per element instead of 28+ for the unfused sequence
 (pad_in read+write, decrypt read+write, encode, pad_out read+write, add).
-On a TPU v5e it moves those 12 B at about a quarter of HBM bandwidth. At
-blocks of 64 rows each grid step's fixed cost sets the bound, not the
-VPU's throughput: halving the Threefry work cut the hop by a tenth, a
-block of 1,024 rows by three fifths (PERF.md §5).
+Each grid step has a fixed cost: at blocks of 64 rows it, not the VPU's
+throughput, set the pace on a TPU v5e. So the grid's block is taken from
+the row count (``block_rows_for``, 1,024 rows when there are that many)
+and the body works it in 64-row sub-tiles (PERF.md §6).
 
 The wrapper runs the kernel over a (rows, 128) view of its operands
 (``lane_view``) and lets the output take the incoming cipher's buffer
@@ -40,8 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.threefry_mask_add import (
     LANE,
     as_u32_scalar,
-    DEFAULT_BLOCK_ROWS,
     encode_block,
+    for_each_subtile,
     lane_view,
     pad_for_block,
 )
@@ -51,11 +51,17 @@ from repro.obs.trace import CHAIN_COMBINE, TILE_PAD, TILE_SLICE
 def _chain_combine_kernel(scalars, cipher_ref, x_ref, o_ref, *,
                           scale_bits: int, block_rows: int):
     i = pl.program_id(0)
-    off = jnp.uint32(i * block_rows)
-    # scalars = [kin0, kin1, kout0, kout1, base]
-    pad_in = pad_for_block(scalars[0], scalars[1], scalars[4], cipher_ref.shape, off)
-    pad_out = pad_for_block(scalars[2], scalars[3], scalars[4], cipher_ref.shape, off)
-    o_ref[...] = cipher_ref[...] - pad_in + encode_block(x_ref[...], scale_bits) + pad_out
+
+    def tile(r, n):
+        off = jnp.uint32(i * block_rows + r)
+        # scalars = [kin0, kin1, kout0, kout1, base]
+        pad_in = pad_for_block(scalars[0], scalars[1], scalars[4], (n, LANE), off)
+        pad_out = pad_for_block(scalars[2], scalars[3], scalars[4], (n, LANE), off)
+        rows = pl.ds(r, n)
+        o_ref[rows, :] = (cipher_ref[rows, :] - pad_in
+                          + encode_block(x_ref[rows, :], scale_bits) + pad_out)
+
+    for_each_subtile(block_rows, tile)
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "block_rows", "interpret"))
@@ -67,10 +73,11 @@ def chain_combine(
     counter_base: jax.Array | int = 0,
     *,
     scale_bits: int = 16,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """One fused chain hop. cipher: uint32[V], x: f32[V] -> uint32[V].
+    ``block_rows`` None takes the grid's block from the rows.
 
     The output is written over ``cipher``'s (rows, 128) view: donate
     ``cipher`` to the enclosing jit and, for V a multiple of 128, the hop
@@ -79,8 +86,8 @@ def chain_combine(
     """
     V = cipher.shape[0]
     with jax.named_scope(TILE_PAD):
-        c2, nblocks = lane_view(cipher, block_rows)
-        x2, _ = lane_view(x, block_rows)
+        c2, block_rows, nblocks = lane_view(cipher, block_rows)
+        x2, _, _ = lane_view(x, block_rows)
 
     scalars = jnp.concatenate([
         jnp.asarray(key_in, jnp.uint32).reshape(2),
@@ -115,16 +122,21 @@ def _chain_combine_batched_kernel(scalars, cipher_ref, x_ref, o_ref, *,
                                   scale_bits: int, block_rows: int):
     s = pl.program_id(0)  # session
     i = pl.program_id(1)  # block within the session's vector
-    off = jnp.uint32(i * block_rows)
     # per-session scalars at s*5: [kin0, kin1, kout0, kout1, base]
     b = s * 5
-    shape = (block_rows, LANE)
-    pad_in = pad_for_block(scalars[b], scalars[b + 1], scalars[b + 4],
-                           shape, off)
-    pad_out = pad_for_block(scalars[b + 2], scalars[b + 3], scalars[b + 4],
-                            shape, off)
-    o_ref[0] = (cipher_ref[0] - pad_in
-                + encode_block(x_ref[0], scale_bits) + pad_out)
+
+    def tile(r, n):
+        off = jnp.uint32(i * block_rows + r)
+        pad_in = pad_for_block(scalars[b], scalars[b + 1], scalars[b + 4],
+                               (n, LANE), off)
+        pad_out = pad_for_block(scalars[b + 2], scalars[b + 3],
+                                scalars[b + 4], (n, LANE), off)
+        rows = pl.ds(r, n)
+        o_ref[0, rows, :] = (cipher_ref[0, rows, :] - pad_in
+                             + encode_block(x_ref[0, rows, :], scale_bits)
+                             + pad_out)
+
+    for_each_subtile(block_rows, tile)
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "block_rows",
@@ -137,7 +149,7 @@ def chain_combine_batched(
     counter_bases: jax.Array,
     *,
     scale_bits: int = 16,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """S fused chain hops, one per session, in one kernel launch.
@@ -151,6 +163,8 @@ def chain_combine_batched(
       x: f32[S, V] local vectors.
       keys_in / keys_out: uint32[S, 2] per-session edge keys.
       counter_bases: uint32[S] per-session counter bases.
+      block_rows: the grid's block; None takes it from the rows of one
+        session's (rows, 128) view.
 
     Returns:
       uint32[S, V] outgoing ciphertexts, in a new buffer.
@@ -164,8 +178,8 @@ def chain_combine_batched(
     copy of all S rows.
     """
     S, V = cipher.shape
-    c3, nblocks = lane_view(cipher, block_rows)
-    x3, _ = lane_view(x, block_rows)
+    c3, block_rows, nblocks = lane_view(cipher, block_rows)
+    x3, _, _ = lane_view(x, block_rows)
 
     # flat SMEM table [S*5]: rows of (kin0, kin1, kout0, kout1, base)
     scalars = jnp.concatenate([

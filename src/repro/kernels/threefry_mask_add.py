@@ -7,10 +7,11 @@ add); this kernel does one read of ``x`` and one write of the masked
 ciphertext — the pad never touches HBM.
 
 TPU adaptation notes (DESIGN.md §4):
-  * masking is element-wise VPU work, and HBM does not set the bound: on
-    a TPU v5e the hop kernel moves its 12 B/word at about a quarter of
-    HBM bandwidth, bound at 64-row blocks by each grid step's fixed cost
-    (PERF.md §5). Fusion still saves the pad's HBM round trips;
+  * masking is element-wise VPU work; fusion saves the pad's HBM round
+    trips. Each grid step has a fixed cost, so the grid's block is large
+    (``block_rows_for``: 1,024 rows when the update has them), and the
+    body works it in sub-tiles of 64 rows (``for_each_subtile``), each
+    the (32, 128) counter tile of a 64-row block (PERF.md §6);
   * blocks are (block_rows, 128): lane-dim 128 matches the VPU/VREG lane
     width, block_rows a multiple of 8 for f32 sublane packing;
   * the wrappers run the grid over a (rows, 128) view of the update
@@ -47,12 +48,44 @@ def as_u32_scalar(x):
     if isinstance(x, (int, np.integer)):
         return jnp.asarray(np.uint32(int(x) & 0xFFFFFFFF))
     return jnp.asarray(x, jnp.uint32)
-DEFAULT_BLOCK_ROWS = 64  # 64×128 u32 = 32 KiB / block operand — fits VMEM easily
 
 
-def lane_view(a: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
-    """``a[..., V]`` as ``[..., rows, LANE]``, and the grid's block count
-    over those rows, ``cdiv(rows, block_rows)``.
+BLOCK_ROWS = 1024  # 1024×128 u32 = 512 KiB / block operand
+SUB_ROWS = 64  # rows the body computes at once: a (32, 128) counter tile
+
+
+def block_rows_for(rows: int) -> int:
+    """The grid's block height for a (rows, LANE) view: the rows spread
+    evenly over ``cdiv(rows, BLOCK_ROWS)`` blocks, each rounded up to a
+    multiple of 8 up to ``SUB_ROWS`` rows and of ``SUB_ROWS`` above. So
+    a large view takes ``BLOCK_ROWS``-row blocks, a smaller one the
+    smallest block that covers it, and no last block is mostly empty."""
+    per_block = pl.cdiv(rows, pl.cdiv(rows, BLOCK_ROWS))
+    step = 8 if per_block <= SUB_ROWS else SUB_ROWS
+    return max(step, pl.cdiv(per_block, step) * step)
+
+
+def for_each_subtile(block_rows: int, tile) -> None:
+    """Calls ``tile(r, n)`` for the block's sub-tiles, rows [r, r + n),
+    through a ``fori_loop``: ``SUB_ROWS`` rows each, or the whole block
+    once when it is no larger."""
+    sub = min(SUB_ROWS, block_rows)
+    assert block_rows % sub == 0, (
+        f"a block of {block_rows} rows is no whole number of {sub}-row "
+        f"sub-tiles")
+
+    def step(j, carry):
+        tile(pl.multiple_of(j * sub, sub), sub)
+        return carry
+
+    jax.lax.fori_loop(0, block_rows // sub, step, 0)
+
+
+def lane_view(a: jax.Array, block_rows: int | None
+              ) -> tuple[jax.Array, int, int]:
+    """``a[..., V]`` as ``[..., rows, LANE]``, the grid's block height
+    (``block_rows``, or ``block_rows_for(rows)`` when it is None) and its
+    block count over those rows, ``cdiv(rows, block)``.
 
     V is padded only to whole rows, not to whole blocks: the grid's last
     block may run past ``rows``, where Pallas reads don't-care values
@@ -65,7 +98,9 @@ def lane_view(a: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
     vpad = (-a.shape[-1]) % LANE
     a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, vpad)])
     view = a.reshape(*a.shape[:-1], -1, LANE)
-    return view, pl.cdiv(view.shape[-2], block_rows)
+    rows = view.shape[-2]
+    block = block_rows_for(rows) if block_rows is None else block_rows
+    return view, block, pl.cdiv(rows, block)
 
 
 def _rotl32(x, d: int):
@@ -128,9 +163,14 @@ def encode_block(x, scale_bits: int):
 
 def _mask_add_kernel(scalars, x_ref, o_ref, *, scale_bits: int, block_rows: int):
     i = pl.program_id(0)
-    pad = pad_for_block(scalars[0], scalars[1], scalars[2], x_ref.shape,
-                        jnp.uint32(i * block_rows))
-    o_ref[...] = encode_block(x_ref[...], scale_bits) + pad
+
+    def tile(r, n):
+        pad = pad_for_block(scalars[0], scalars[1], scalars[2], (n, LANE),
+                            jnp.uint32(i * block_rows + r))
+        rows = pl.ds(r, n)
+        o_ref[rows, :] = encode_block(x_ref[rows, :], scale_bits) + pad
+
+    for_each_subtile(block_rows, tile)
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "block_rows", "interpret"))
@@ -140,17 +180,18 @@ def mask_add(
     counter_base: jax.Array | int = 0,
     *,
     scale_bits: int = 16,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """out[i] = encode(x[i]) + PRF(key, base + i)  (mod 2^32), fused.
 
     x: f32[V], any V: viewed as (rows, 128) in place when V is a multiple
     of 128, else padded to the next multiple (``lane_view``).
-    key: uint32[2]. Returns uint32[V], a new buffer.
+    key: uint32[2]. Returns uint32[V], a new buffer. ``block_rows`` None
+    takes the grid's block from the rows (``block_rows_for``).
     """
     V = x.shape[0]
-    x2, nblocks = lane_view(x, block_rows)
+    x2, block_rows, nblocks = lane_view(x, block_rows)
 
     scalars = jnp.concatenate(
         [jnp.asarray(key, jnp.uint32).reshape(2),

@@ -18,7 +18,8 @@ from repro.kernels.ops import (bon_mask, chain_combine,
                                chain_combine_batched, mask_add)
 from repro.kernels.ref import (bon_mask_ref, chain_combine_batched_ref,
                                chain_combine_ref, mask_add_ref)
-from repro.kernels.threefry_mask_add import LANE, pad_for_block
+from repro.kernels.threefry_mask_add import (BLOCK_ROWS, LANE, SUB_ROWS,
+                                             block_rows_for, pad_for_block)
 from repro.kernels.threefry_mask_add import mask_add as raw_mask_add
 
 #: the kinds of V the wrappers lay out differently, each viewed in place
@@ -53,7 +54,7 @@ def test_mask_add_counter_bases(V, base):
         np.asarray(mask_add_ref(x, key, base)))
 
 
-@pytest.mark.parametrize("block_rows", [8, 16, 64])
+@pytest.mark.parametrize("block_rows", [8, 16, 64, 1024])
 def test_mask_add_block_shapes(block_rows):
     V = 3000
     x = jnp.asarray(np.random.RandomState(1).uniform(-10, 10, V).astype(np.float32))
@@ -61,6 +62,63 @@ def test_mask_add_block_shapes(block_rows):
     got = raw_mask_add(x, key, 0, block_rows=block_rows, interpret=True)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(mask_add_ref(x, key, 0)))
+
+
+def test_block_rows_for():
+    """A large update takes the large block; a smaller one the smallest
+    block that covers it, a multiple of 8, and of the sub-tile above it;
+    one just over a block spreads its rows over two even blocks."""
+    assert block_rows_for(3_857_448) == BLOCK_ROWS == 1024
+    assert block_rows_for(3_892_686) == BLOCK_ROWS
+    for rows in (8, 24, 100):
+        multiple = 8 if rows <= SUB_ROWS else SUB_ROWS
+        block = block_rows_for(rows)
+        assert block % multiple == 0 and rows <= block < rows + multiple
+    assert [block_rows_for(r) for r in (1, 8, 24, 100)] == [8, 8, 24, 128]
+    for rows in (BLOCK_ROWS + 1, 2 * BLOCK_ROWS + 1, 3_857_448):
+        block = block_rows_for(rows)
+        nblocks = -(-rows // block)
+        assert nblocks == -(-rows // BLOCK_ROWS)
+        assert block <= BLOCK_ROWS and block % SUB_ROWS == 0
+        assert nblocks * block - rows < nblocks * SUB_ROWS
+    assert block_rows_for(BLOCK_ROWS + 1) == 576
+
+
+#: rows over three default blocks of ``BLOCK_ROWS`` rows, ending in a
+#: ragged block and a part row
+V_LARGE_BLOCKS = (3 * BLOCK_ROWS - 40) * LANE + 5
+
+
+@pytest.mark.parametrize("kernel", ["mask_add", "chain_combine",
+                                    "chain_combine_batched", "bon_mask"])
+def test_kernels_at_default_block_match_ref(kernel):
+    """Each wrapper at its default block, which it takes from the rows,
+    equals ``kernels/ref.py`` word for word: compiled on a TPU,
+    interpreted on the CPU."""
+    V, S = V_LARGE_BLOCKS, 2
+    rng = np.random.RandomState(18)
+
+    def u32(*shape):
+        return jnp.asarray(rng.randint(0, 2**32, shape, dtype=np.uint64)
+                           .astype(np.uint32))
+
+    x = jnp.asarray(rng.uniform(-50, 50, (S, V)).astype(np.float32))
+    cipher, kin, kout = u32(S, V), u32(S, 2), u32(S, 2)
+    if kernel == "mask_add":
+        got, want = mask_add(x[0], kin[0], 9), mask_add_ref(x[0], kin[0], 9)
+    elif kernel == "chain_combine":
+        args = (cipher[0], x[0], kin[0], kout[0], 2**32 - 7)
+        got, want = chain_combine(*args), chain_combine_ref(*args)
+    elif kernel == "chain_combine_batched":
+        args = (cipher, x, kin, kout, u32(S))
+        got = chain_combine_batched(*args)
+        want = chain_combine_batched_ref(*args)
+    else:
+        signs = jnp.asarray([1, -1, 1], jnp.int32)
+        keys = u32(3, 2)
+        got = bon_mask(x[0], keys, signs, 5)
+        want = bon_mask_ref(x[0], keys, signs, 5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def _pads(key, base, V, block_rows):
